@@ -163,4 +163,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.substrate import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -42,4 +42,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.substrate import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -138,4 +138,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.substrate import enable_compile_cache
+    enable_compile_cache()
     main()

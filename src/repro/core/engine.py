@@ -50,6 +50,7 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core.prox import soft_threshold
 from repro.core.solvers import lasso_stats_step_scale, power_iteration
+from repro.kernels import common as kernel_common
 from repro.kernels.ista_step.ops import fista_step_batched
 from repro.kernels.ista_step.ref import (
     fista_step_batched_ref, ista_step_batched_ref,
@@ -64,21 +65,15 @@ def power_iteration_batched(Sigmas: jnp.ndarray, iters: int = 64) -> jnp.ndarray
     return jax.vmap(partial(power_iteration, iters=iters))(Sigmas)
 
 
-def _trace_clean() -> bool:
-    # fail CLOSED when the installed jax no longer exposes the probe:
-    # skipping a telemetry record is free, scalarizing a tracer is not
-    return bool(getattr(jax.core, "trace_state_clean", lambda: False)())
-
-
-def _record_solve(kind: str, n_iters, ceiling: int) -> None:
+def _record_solve(kind: str, n_iters, ceiling: int, out) -> None:
     """Record a solve's iterations-used vs its `iters` ceiling (and the
     early-exit verdict the `tol=`/`return_iters` machinery implies).
     Eager-only by construction: when a caller jits a public wrapper the
-    whole wrapper body runs under trace and `int(n_iters)` would
-    scalarize a tracer — so this is a no-op unless the trace state is
-    clean (RL107 territory; RL108 additionally lint-proves no jit root
+    whole wrapper body runs under trace, the solve's output `out` is a
+    tracer and `int(n_iters)` would scalarize one — so this is a no-op
+    then (RL107 territory; RL108 additionally lint-proves no jit root
     in this module can reach an obs call)."""
-    if not obs.enabled() or not _trace_clean():
+    if not obs.enabled() or isinstance(out, jax.core.Tracer):
         return
     used = int(n_iters)
     obs.inc("engine.solve.calls", kind=kind)
@@ -113,7 +108,7 @@ def sufficient_stats(Xs: jnp.ndarray, ys: jnp.ndarray,
     """
     m, n, p = Xs.shape
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = kernel_common.kernels_by_default()
     block = resolve_rank_block_policy(m, n, p, Xs.dtype, block, use_kernel)
     return rank_update(Xs, ys, weights, use_kernel=use_kernel,
                        interpret=interpret, block=block)
@@ -151,18 +146,22 @@ def resolve_block_policy(m: int, p: int, r: int, dtype, block,
                          use_kernel: bool):
     """Engine v2 block policy: an explicit `block` (int or (bp, br, bk)
     triple) always wins; otherwise, when the kernel path is active, the
-    autotuned winner for (backend, m, p, r, dtype) is looked up (and
-    timed once on a miss). The oracle path never consults the cache."""
-    from repro.kernels.ista_step.ops import is_ragged, resolve_blocks
+    autotuned winner for (backend, m, p, r, dtype) is looked up — the
+    deterministic default on a miss: sweeps run only from eager
+    `autotune.warmup_cache`, never under a trace. The oracle path never
+    consults the cache."""
+    from repro.kernels.ista_step.ops import (
+        resolve_blocks, step_routes_to_oracle,
+    )
     if block is not None:
         resolve_blocks(p, r, block)   # malformed blocks raise on EVERY
         return block                  # path, not just the kernel one
-    if not use_kernel or is_ragged(p, r):
-        # the kernel dispatcher routes ragged shapes to the jnp oracle,
-        # which ignores blocks — never pay (or pollute) a sweep for them
+    if not use_kernel or step_routes_to_oracle(p, r):
+        # the kernel dispatcher routes these shapes to the jnp oracle,
+        # which ignores blocks — never consult the cache for them
         return 128
     from repro.kernels.autotune import autotune_block
-    return autotune_block(m, p, r, dtype=dtype)
+    return autotune_block(m, p, r, dtype=dtype, sweep=False)
 
 
 def resolve_logistic_block_policy(m: int, n: int, p: int, dtype, block,
@@ -180,7 +179,7 @@ def resolve_logistic_block_policy(m: int, n: int, p: int, dtype, block,
     if not use_kernel or routes_to_oracle(n, p):
         return None
     from repro.kernels.autotune import autotune_logistic_block
-    return autotune_logistic_block(m, n, p, dtype=dtype)
+    return autotune_logistic_block(m, n, p, dtype=dtype, sweep=False)
 
 
 def resolve_rank_block_policy(m: int, n: int, p: int, dtype, block,
@@ -193,7 +192,7 @@ def resolve_rank_block_policy(m: int, n: int, p: int, dtype, block,
     if not use_kernel or rank_routes_to_oracle(n, p):
         return 128
     from repro.kernels.autotune import autotune_rank_block
-    return autotune_rank_block(m, n, p, dtype=dtype)
+    return autotune_rank_block(m, n, p, dtype=dtype, sweep=False)
 
 
 def solve_lasso_batched(Sigmas: jnp.ndarray, cs: jnp.ndarray, lam, *,
@@ -229,14 +228,14 @@ def solve_lasso_batched(Sigmas: jnp.ndarray, cs: jnp.ndarray, lam, *,
     m = cs.shape[0]
     r = 1 if cs.ndim == 2 else cs.shape[-1]
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = kernel_common.kernels_by_default()
     block = resolve_block_policy(m, cs.shape[1], r, cs.dtype, block,
                                  use_kernel)
     out, n_iters = _solve_lasso_batched(
         Sigmas, cs, lam, etas, beta0, tol, iters=iters,
         use_kernel=use_kernel, interpret=interpret, block=block,
         check_every=check_every)
-    _record_solve("lasso", n_iters, iters)
+    _record_solve("lasso", n_iters, iters, out)
     return (out, n_iters) if return_iters else out
 
 
@@ -304,12 +303,12 @@ def solve_lasso_grid(Sigmas: jnp.ndarray, cs: jnp.ndarray,
     lams = jnp.asarray(lams, cs.dtype)
     k = lams.shape[0]
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = kernel_common.kernels_by_default()
     block = resolve_block_policy(k * m, p, 1, cs.dtype, block, use_kernel)
     B = _solve_lasso_grid(Sigmas, cs, lams, etas, iters=iters,
                           use_kernel=use_kernel, interpret=interpret,
                           block=block)
-    _record_solve("lasso_grid", iters, iters)
+    _record_solve("lasso_grid", iters, iters, B)
     return B
 
 
@@ -338,6 +337,7 @@ def solve_lasso_eq2(Sigmas: jnp.ndarray, cs: jnp.ndarray, lam, *,
                     beta0: jnp.ndarray | None = None,
                     lam_max: jnp.ndarray | None = None,
                     tol=None, check_every: int = 25,
+                    use_kernel: bool | None = None,
                     return_iters: bool = False) -> jnp.ndarray:
     """Batched lasso in the PAPER'S eq.-2 convention:
 
@@ -358,14 +358,16 @@ def solve_lasso_eq2(Sigmas: jnp.ndarray, cs: jnp.ndarray, lam, *,
     every `check_every` iterations) — this is the latency-budget lever
     the streaming refit path leans on: a warm-started refit under a tol
     exits in a fraction of the ceiling, and the ceiling bounds the
-    worst case. `return_iters` also returns the iterations run."""
+    worst case. `return_iters` also returns the iterations run.
+    `use_kernel` as in `solve_lasso_batched` (default: only on TPU)."""
     m, p = cs.shape
-    use_kernel = jax.default_backend() == "tpu"
+    if use_kernel is None:
+        use_kernel = kernel_common.kernels_by_default()
     block = resolve_block_policy(m, p, 1, cs.dtype, None, use_kernel)
     out, n_iters = _solve_lasso_eq2(Sigmas, cs, lam, beta0, lam_max, tol,
                                     iters=iters, use_kernel=use_kernel,
                                     block=block, check_every=check_every)
-    _record_solve("lasso_eq2", n_iters, iters)
+    _record_solve("lasso_eq2", n_iters, iters, out)
     return (out, n_iters) if return_iters else out
 
 
@@ -391,11 +393,11 @@ def solve_lasso_eq2_grid(Sigmas: jnp.ndarray, cs: jnp.ndarray, lams, *,
     m, p = cs.shape
     lams = jnp.asarray(lams, cs.dtype)
     k = lams.shape[0]
-    use_kernel = jax.default_backend() == "tpu"
+    use_kernel = kernel_common.kernels_by_default()
     block = resolve_block_policy(k * m, p, 1, cs.dtype, None, use_kernel)
     out = _solve_lasso_eq2_grid(Sigmas, cs, lams, iters=iters,
                                 use_kernel=use_kernel, block=block)
-    _record_solve("lasso_eq2_grid", iters, iters)
+    _record_solve("lasso_eq2_grid", iters, iters, out)
     return out
 
 
@@ -452,14 +454,14 @@ def solve_logistic_lasso_batched(Xs: jnp.ndarray, ys: jnp.ndarray, lam, *,
     """
     m, n, p = Xs.shape
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = kernel_common.kernels_by_default()
     block = resolve_logistic_block_policy(m, n, p, Xs.dtype, block,
                                           use_kernel)
     out, n_iters = _solve_logistic_lasso_batched(
         Xs, ys, lam, etas, beta0, grad_scale, tol, iters=iters, prox=prox,
         momentum=momentum, check_every=check_every, use_kernel=use_kernel,
         interpret=interpret, block=block)
-    _record_solve("logistic", n_iters, iters)
+    _record_solve("logistic", n_iters, iters, out)
     return (out, n_iters) if return_iters else out
 
 
@@ -532,6 +534,7 @@ def inverse_hessian_batched(Sigmas: jnp.ndarray, mu, iters: int = 600,
                             M0: jnp.ndarray | None = None,
                             lam_max: jnp.ndarray | None = None,
                             tol=None, check_every: int = 25,
+                            use_kernel: bool | None = None,
                             return_iters: bool = False) -> jnp.ndarray:
     """Approximate inverse Ms (m, p, p) of a stack of PSD covariances —
     the Javanmard-Montanari program for all tasks and all p rows as ONE
@@ -542,14 +545,16 @@ def inverse_hessian_batched(Sigmas: jnp.ndarray, mu, iters: int = 600,
     `tol=` makes `iters` a ceiling (early exit on the KKT residual,
     checked every `check_every` iterations) so a warm-started streaming
     refit pays only the iterations it needs; `return_iters` also
-    returns the iterations run."""
+    returns the iterations run. `use_kernel` as in
+    `solve_lasso_batched` (default: only on TPU)."""
     m, p, _ = Sigmas.shape
-    use_kernel = jax.default_backend() == "tpu"
+    if use_kernel is None:
+        use_kernel = kernel_common.kernels_by_default()
     block = resolve_block_policy(m, p, p, Sigmas.dtype, None, use_kernel)
     out, n_iters = _inverse_hessian_batched(
         Sigmas, mu, M0, lam_max, tol, iters=iters,
         use_kernel=use_kernel, block=block, check_every=check_every)
-    _record_solve("debias", n_iters, iters)
+    _record_solve("debias", n_iters, iters, out)
     return (out, n_iters) if return_iters else out
 
 

@@ -15,16 +15,21 @@ Each `autotune_*` entry point times the candidate tilings for a given
 problem key once, then serves the winner from an in-process cache
 backed by a JSON file under the repo cache dir (`.cache/autotune.json`,
 override with $REPRO_CACHE_DIR), so a process restart never re-times a
-known key. Cache keys are NAMESPACED PER KERNEL
+known key. Every candidate obeys the TPU's (8, 128) tiling rule: a tile
+on a lane axis is a multiple of 128 or the whole axis, a tile on a
+sublane axis a multiple of 8. Cache keys are NAMESPACED PER KERNEL
 (`"<kernel>/<backend>_<dims>_<dtype>"`); legacy un-namespaced entries
 (pre-namespace files were written only by the fista sweep) are migrated
 to `fista_step/...` on load.
 
-The engine (`core/engine.py`) uses these as its default block policies:
-`solve_lasso_batched(block=None)` / `solve_logistic_lasso_batched
-(block=None)` / `sufficient_stats(block=None)` on the kernel path look
-the winner up here; an explicit `block=` always wins and never touches
-the cache.
+Sweeps run only from eager calls: `warmup_cache` (what
+`StreamingDsmlService` calls at construction) and direct `autotune_*`
+calls. The engine (`core/engine.py`) uses these as its default block
+policies with `sweep=False`: `solve_lasso_batched(block=None)` /
+`solve_logistic_lasso_batched(block=None)` / `sufficient_stats
+(block=None)` on the kernel path — which often run under a caller's
+jit trace — look the winner up and serve the deterministic default on
+a miss; an explicit `block=` always wins and never touches the cache.
 """
 from __future__ import annotations
 
@@ -39,8 +44,11 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.kernels.common import LANE, lane_fit_block, on_tpu
 from repro.kernels.ista_step.kernel import fista_step_batched_pallas
-from repro.kernels.ista_step.ops import resolve_blocks
+from repro.kernels.ista_step.ops import (
+    STEP_VMEM_BUDGET, resolve_blocks, step_vmem_bytes,
+)
 from repro.kernels.logistic_grad.kernel import logistic_grad_pallas
 from repro.kernels.logistic_grad.ops import (
     LOGISTIC_VMEM_BUDGET, kernel_vmem_bytes, resolve_logistic_blocks,
@@ -51,9 +59,12 @@ from repro.kernels.rank_update.kernel import rank_update_pallas
 _REPO_ROOT = Path(__file__).resolve().parents[3]
 CACHE_FILE = "autotune.json"
 
-# block candidates per grid axis; intersected with the divisors of the
-# actual dimension, so every candidate is a legal BlockSpec tiling
-BLOCK_CANDIDATES = (32, 64, 128, 256)
+# block candidates per grid axis, intersected with the divisors of the
+# actual dimension: lane axes take 128-multiples (or the whole axis),
+# sublane axes 8-multiples, so every candidate is a tiling the TPU
+# compiler accepts
+LANE_CANDIDATES = (128, 256, 512)
+SUBLANE_CANDIDATES = (32, 64, 128, 256)
 
 _memory_cache: Dict[str, tuple] = {}
 
@@ -129,40 +140,50 @@ def _save_disk(entries: dict) -> None:
         pass  # read-only checkout: the in-process cache still serves
 
 
-def _divisor_candidates(size: int) -> List[int]:
-    return [b for b in BLOCK_CANDIDATES if b <= size and size % b == 0] \
-        or [size]
+def _divisor_candidates(size: int, cands=SUBLANE_CANDIDATES) -> List[int]:
+    return [b for b in cands if b <= size and size % b == 0] or [size]
 
 
 def block_candidates(p: int, r: int) -> List[Tuple[int, int, int]]:
     """Legal (bp, br, bk) tilings to sweep for a (p, r) solve. bk is
     tied to bp (the contraction tile streams the same Sigma rows the
-    output tile covers), so the sweep is |bp| x |br| candidates."""
-    bps = _divisor_candidates(p)
-    brs = [1] if r == 1 else _divisor_candidates(r)
-    return [(bp, br, bp) for bp in bps for br in brs]
+    output tile covers) and lands on lanes, as br does, so both take
+    lane candidates; the sweep is |bp| x |br| candidates inside the
+    step kernel's VMEM budget."""
+    bps = _divisor_candidates(p, LANE_CANDIDATES)
+    brs = [1] if r == 1 else _divisor_candidates(r, LANE_CANDIDATES)
+    return [(bp, br, bp) for bp in bps for br in brs
+            if step_vmem_bytes(bp, br, bp) <= STEP_VMEM_BUDGET]
 
 
 def logistic_candidates(n: int, p: int) -> List[Tuple[int, int]]:
     """Legal (bn, bp) tilings to sweep for a (m, n, p) logistic-gradient
     batch, filtered to the kernel's per-tile VMEM budget. The feature
-    axis adds the large lane tiles (512..4096) and the full-lane bp = p
-    layout on top of the shared candidate grid, so small p sweeps the
-    historical resident slab and large p sweeps real feature tilings."""
-    bps = _divisor_candidates(p)
-    bps += [b for b in (512, 1024, 2048, 4096)
-            if b < p and p % b == 0 and b not in bps]
-    if p not in bps:
-        bps.append(p)
+    axis (lanes) sweeps 128-multiple tiles up to 4096 and the full-lane
+    bp = p layout, so small p sweeps the historical resident slab and
+    large p sweeps real feature tilings."""
+    bps = [b for b in (LANE, 256, 512, 1024, 2048, 4096)
+           if b < p and p % b == 0] + [p]
     pairs = [(bn, bp) for bn in _divisor_candidates(n) for bp in bps
              if kernel_vmem_bytes(p, bn, bp) <= LOGISTIC_VMEM_BUDGET]
     return pairs or [resolve_logistic_blocks(n, p)]
 
 
 def rank_candidates(n: int, p: int) -> List[Tuple[int, int]]:
-    """Legal (bp, bn) tilings to sweep for a (m, n, p) rank-n update."""
-    return [(bp, bn) for bp in _divisor_candidates(p)
-            for bn in _divisor_candidates(n)]
+    """Legal (bp, bn) tilings to sweep for a (m, n, p) rank-n update.
+    The feature tile stays at one 128-lane tile (the whole axis when p
+    has no 128-multiple divisor): on v5e the chip's compiler fails an
+    internal check on the kernel's transposed X tile for some wider
+    feature tiles (bp = 256 with bn = 256, bp = 512 with bn = 128), so
+    the sweep covers the sample tile only, inside the kernel's VMEM
+    budget."""
+    from repro.kernels.rank_update.ops import (
+        RANK_VMEM_BUDGET, rank_vmem_bytes, resolve_rank_blocks,
+    )
+    bp = lane_fit_block(p, LANE)
+    return [(bp, bn) for bn in _divisor_candidates(n)
+            if rank_vmem_bytes(bp, bn) <= RANK_VMEM_BUDGET] \
+        or [resolve_rank_blocks(n, p, 128)]
 
 
 def _time_candidate(fn, reps: int) -> float:
@@ -180,13 +201,19 @@ def _time_candidate(fn, reps: int) -> float:
 
 def _autotune(kernel: str, dims: Dict[str, int], default, candidates,
               make_sweep: Callable, *, dtype, backend: str | None,
-              interpret: bool | None, reps: int, use_disk: bool):
+              interpret: bool | None, reps: int, use_disk: bool,
+              sweep: bool):
     """Shared cache-then-sweep policy behind every `autotune_*` entry
     point. `make_sweep(interp)` builds the synthetic sweep inputs and
     returns a `candidate -> timing thunk` factory — called only when a
     sweep actually runs, so warm-cache hits on the engine hot path
     never pay a problem-sized allocation. The winner is written back to
     both caches.
+
+    With `sweep=False` (the engine's block policies, which run under a
+    caller's jit trace where a timed candidate would return a tracer) a
+    miss serves the deterministic default, uncached, so a later eager
+    `warmup_cache` can still tune the key.
 
     Multi-controller guard: a winner becomes a STATIC compile
     parameter, and a timing sweep is not deterministic across hosts —
@@ -210,17 +237,8 @@ def _autotune(kernel: str, dims: Dict[str, int], default, candidates,
         obs.inc("autotune.cache", kernel=kernel, event="hit_disk")
         return blk
 
-    # A warm cache is servable anywhere (the lookups above), but the
-    # SWEEP must not run while a caller's jit trace is active: the
-    # candidate calls would return tracers, `block_until_ready` would
-    # be a no-op, and trace-time noise would be cached as the permanent
-    # winner. Fall back to the deterministic default — uncached, so a
-    # later eager call (`warmup_cache`) can still tune this key. If the
-    # installed jax no longer exposes trace_state_clean, fail CLOSED
-    # (assume a trace may be active): a never-swept cache serves the
-    # safe default, a trace-noise-poisoned cache is permanent.
-    if not getattr(jax.core, "trace_state_clean", lambda: False)():
-        obs.inc("autotune.cache", kernel=kernel, event="deferred_trace")
+    if not sweep:
+        obs.inc("autotune.cache", kernel=kernel, event="miss_default")
         return default
 
     obs.inc("autotune.cache", kernel=kernel, event="miss_sweep")
@@ -252,14 +270,14 @@ def warmup_cache(m: int, p: int, n: int | None = None, *,
     like any other now that the kernel feature-tiles its slabs.
 
     This is the intended production entry point: every in-repo solver
-    is jitted, and the sweep refuses to run under an active trace
+    is jitted and the engine's block policies only look winners up
     (see `_autotune`), so without an eager warm-up the engine keeps the
     deterministic 128 default. Call once at startup
     (`StreamingDsmlService` does, on TPU). No-op off-TPU, where the
     engine's default path is the jnp oracle and a sweep would time the
     slow interpreter for nothing.
     """
-    if jax.default_backend() != "tpu":
+    if not on_tpu():
         return
     autotune_block(m, p, 1, dtype=dtype, reps=reps)
     autotune_block(m, p, p, dtype=dtype, reps=reps)
@@ -272,7 +290,7 @@ def autotune_block(m: int, p: int, r: int, *, dtype=jnp.float32,
                    backend: str | None = None,
                    interpret: bool | None = None,
                    candidates: List[Tuple[int, int, int]] | None = None,
-                   reps: int = 2, use_disk: bool = True
+                   reps: int = 2, use_disk: bool = True, sweep: bool = True
                    ) -> Tuple[int, int, int]:
     """Winning (bp, br, bk) tiling for a batched FISTA solve step of
     this (m, p, r) shape (kernel namespace `fista_step`)."""
@@ -291,15 +309,15 @@ def autotune_block(m: int, p: int, r: int, *, dtype=jnp.float32,
         resolve_blocks(p, r, 128),
         block_candidates(p, r) if candidates is None else candidates,
         make_sweep, dtype=dtype, backend=backend, interpret=interpret,
-        reps=reps, use_disk=use_disk)
+        reps=reps, use_disk=use_disk, sweep=sweep)
 
 
 def autotune_logistic_block(m: int, n: int, p: int, *, dtype=jnp.float32,
                             backend: str | None = None,
                             interpret: bool | None = None,
                             candidates: List[Tuple[int, int]] | None = None,
-                            reps: int = 2, use_disk: bool = True
-                            ) -> Tuple[int, int]:
+                            reps: int = 2, use_disk: bool = True,
+                            sweep: bool = True) -> Tuple[int, int]:
     """Winning (bn, bp) tiling for a (m, n, p) fused logistic-gradient
     batch (kernel namespace `logistic_grad`). Feature-tiled large-p
     shapes sweep too — the old full-lane p cliff routed them to the
@@ -322,15 +340,15 @@ def autotune_logistic_block(m: int, n: int, p: int, *, dtype=jnp.float32,
         "logistic_grad", {"m": m, "n": n, "p": p}, default,
         logistic_candidates(n, p) if candidates is None else candidates,
         make_sweep, dtype=dtype, backend=backend, interpret=interpret,
-        reps=reps, use_disk=use_disk)
+        reps=reps, use_disk=use_disk, sweep=sweep)
 
 
 def autotune_rank_block(m: int, n: int, p: int, *, dtype=jnp.float32,
                         backend: str | None = None,
                         interpret: bool | None = None,
                         candidates: List[Tuple[int, int]] | None = None,
-                        reps: int = 2, use_disk: bool = True
-                        ) -> Tuple[int, int]:
+                        reps: int = 2, use_disk: bool = True,
+                        sweep: bool = True) -> Tuple[int, int]:
     """Winning (bp, bn) tiling for a (m, n, p) fused rank-n statistics
     update (kernel namespace `rank_update`). As in the logistic sweep,
     shapes the dispatcher routes to the oracle (ragged, sliver tiles)
@@ -355,4 +373,4 @@ def autotune_rank_block(m: int, n: int, p: int, *, dtype=jnp.float32,
         resolve_rank_blocks(n, p, 128),
         rank_candidates(n, p) if candidates is None else candidates,
         make_sweep, dtype=dtype, backend=backend, interpret=interpret,
-        reps=reps, use_disk=use_disk)
+        reps=reps, use_disk=use_disk, sweep=sweep)

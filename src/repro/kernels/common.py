@@ -14,6 +14,30 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def kernels_by_default() -> bool:
+    """The `use_kernel=None` default of every engine entry point: the
+    Pallas kernels on TPU, the jnp oracles elsewhere. Kept apart from
+    `on_tpu` (which picks interpret mode) so a CPU rehearsal can run
+    the kernel path in interpret mode by patching this one function."""
+    return on_tpu()
+
+
+# the TPU's (8, 128) register tile: a block's last dimension must be a
+# multiple of LANE or the whole axis, its second-to-last a multiple of
+# 8 (`aligned_fit_block`) or the whole axis
+LANE = 128
+
+
+def lane_fit_block(size: int, block: int) -> int:
+    """Largest multiple of 128 that divides `size` and is <= `block`
+    (at least 128) — the legal tile for an axis that lands on the TPU's
+    lanes. An axis with no 128-multiple divisor takes the whole axis,
+    the only other tile the compiler accepts there."""
+    if size % LANE:
+        return size
+    return LANE * fit_block(size // LANE, max(block // LANE, 1))
+
+
 def fit_block(size: int, block: int) -> int:
     """Largest divisor of `size` that is <= `block` — the legal tile
     closest to the requested one. (NOT the halving loop of the older

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.common import (
-    aligned_fit_block, record_route, validate_block,
+    LANE, aligned_fit_block, lane_fit_block, record_route, validate_block,
 )
 from repro.kernels.common import on_tpu as _on_tpu
 from repro.kernels.ista_step.kernel import (
@@ -26,11 +26,26 @@ from repro.kernels.ista_step.ref import (
 
 
 def is_ragged(p: int, r: int) -> bool:
-    """THE kernel routing predicate: shapes the pallas tiling cannot
-    legally cover go to the jnp oracle (which ignores blocks). Shared
-    by the step dispatchers below and the engine's block policy so the
-    two can never desync."""
+    """The shape half of the kernel routing predicate: shapes the
+    pallas tiling cannot legally cover go to the jnp oracle (which
+    ignores blocks). Shared by the step dispatchers below and the
+    engine's block policy so the two can never desync."""
     return bool(p % 8 or (r % 8 and r != 1))
+
+
+# per-dispatch VMEM budget for one grid step, the same 8 MB envelope
+# as the sample-streaming kernels
+STEP_VMEM_BUDGET = 8 * 1024 * 1024
+
+
+def step_vmem_bytes(bp: int, br: int, bk: int) -> int:
+    """Estimated VMEM footprint of one grid step of the batched step
+    kernels: the (bp, bk) Sigma tile and the (bk, br) contraction tile,
+    the five (bp, br) iterate/c/output tiles, all double-buffered at
+    f32 with lanes padded to full 128-lane register tiles, plus the f32
+    (bp, br) accumulator."""
+    pad = lambda d: -(-d // LANE) * LANE
+    return 8 * bp * pad(bk) + 8 * bk * pad(br) + 44 * bp * pad(br)
 
 
 def resolve_blocks(p: int, r: int, block) -> tuple:
@@ -38,16 +53,36 @@ def resolve_blocks(p: int, r: int, block) -> tuple:
 
     `block` is either one int (square bp = bk tiles, the historical
     policy) or an explicit (bp, br, bk) triple, e.g. an autotuned winner
-    from `repro.kernels.autotune`; each entry is clipped to the largest
-    aligned divisor of its dimension so ragged-adjacent shapes stay
-    legal (the old local halving clip bottomed non-divisor requests
-    like 48-on-80 out at single-element tiles).
-    Anything else raises — a wrong-arity tuple (e.g. a (bp, bn) rank
-    pair) must not be silently unpacked into the wrong axes.
+    from `repro.kernels.autotune`. Each entry is fitted to the TPU's
+    (8, 128) tiling: bp, the sublane axis of every tile, to the largest
+    8-aligned divisor of p; bk and br, which land on lanes, to the
+    largest 128-multiple divisor of their axis or the whole axis (so
+    the paper's p = 200 takes (40, 1, 200), never a (40, 40) block the
+    compiler refuses). Anything else raises — a wrong-arity tuple (e.g.
+    a (bp, bn) rank pair) must not be silently unpacked into the wrong
+    axes.
     """
     bp, br, bk = validate_block(block, 3, "(bp, br, bk)")
-    return (aligned_fit_block(p, bp), aligned_fit_block(r, br),
-            aligned_fit_block(p, bk))
+    return (aligned_fit_block(p, bp), lane_fit_block(r, br),
+            lane_fit_block(p, bk))
+
+
+def step_route_reason(p: int, r: int, block=128):
+    """Routing verdict plus its telemetry label: None on the kernel
+    path, else `ragged` (an axis the tiling cannot cover) or
+    `vmem_budget` (the legal tiles — whole-axis lane tiles for p or r
+    with no 128-multiple divisor — outgrow `STEP_VMEM_BUDGET`)."""
+    bp, br, bk = resolve_blocks(p, r, block)
+    if is_ragged(p, r):
+        return "ragged"
+    if step_vmem_bytes(bp, br, bk) > STEP_VMEM_BUDGET:
+        return "vmem_budget"
+    return None
+
+
+def step_routes_to_oracle(p: int, r: int, block=128) -> bool:
+    """Routing predicate shared with the engine's block policy."""
+    return step_route_reason(p, r, block) is not None
 
 
 def ista_step_batched(Sigmas, betas, cs, etas, lam, *, block: int = 128,
@@ -69,9 +104,9 @@ def ista_step_batched(Sigmas, betas, cs, etas, lam, *, block: int = 128,
     # a malformed block must raise on every path
     bp, br, bk = resolve_blocks(p, r, block)
     interp = (not _on_tpu()) if interpret is None else interpret
-    record_route("ista_step_batched", "ragged" if is_ragged(p, r) else None,
-                 blocks=(bp, br, bk))
-    if is_ragged(p, r):
+    reason = step_route_reason(p, r, block)
+    record_route("ista_step_batched", reason, blocks=(bp, br, bk))
+    if reason is not None:
         out = ista_step_batched_ref(Sigmas, betas, cs, etas, lam)
     else:
         out = ista_step_batched_pallas(Sigmas, betas, cs, etas, lam,
@@ -96,9 +131,9 @@ def fista_step_batched(Sigmas, zs, xs, cs, etas, lam, theta, *,
     m, p, r = zs.shape
     bp, br, bk = resolve_blocks(p, r, block)    # validate on every path
     interp = (not _on_tpu()) if interpret is None else interpret
-    record_route("fista_step_batched", "ragged" if is_ragged(p, r) else None,
-                 blocks=(bp, br, bk))
-    if is_ragged(p, r):
+    reason = step_route_reason(p, r, block)
+    record_route("fista_step_batched", reason, blocks=(bp, br, bk))
+    if reason is not None:
         xn, zn = fista_step_batched_ref(Sigmas, zs, xs, cs, etas, lam, theta)
     else:
         xn, zn = fista_step_batched_pallas(Sigmas, zs, xs, cs, etas, lam,
@@ -117,9 +152,9 @@ def ista_step(Sigma, beta, c, eta, lam, *, block: int = 128,
     p, r = beta.shape
     bp, br, bk = resolve_blocks(p, r, block)    # validate on every path
     interp = (not _on_tpu()) if interpret is None else interpret
-    record_route("ista_step", "ragged" if is_ragged(p, r) else None,
-                 blocks=(bp, br, bk))
-    if is_ragged(p, r):
+    reason = step_route_reason(p, r, block)
+    record_route("ista_step", reason, blocks=(bp, br, bk))
+    if reason is not None:
         out = ista_step_ref(Sigma, beta, c, eta, lam)   # ragged fallback
     else:
         out = ista_step_pallas(Sigma, beta, c, eta, lam, bp=bp, br=br,

@@ -22,7 +22,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 from repro.kernels.common import (
-    MIN_TILE, aligned_fit_block, degrades_to_slivers, on_tpu,
+    LANE, aligned_fit_block, degrades_to_slivers, lane_fit_block, on_tpu,
     record_route, validate_block,
 )
 from repro.kernels.common import is_ragged_samples  # re-export (tests/engine)
@@ -65,13 +65,14 @@ _BLOCK_ARITIES = (0, 1, 2)
 
 
 def _budget_bp(p: int, bn: int) -> int:
-    """Largest aligned-divisor feature tile whose grid step fits the
-    VMEM budget — bp = p (the resident full-lane layout) whenever it
-    fits."""
-    bp = aligned_fit_block(p, min(p, max(LOGISTIC_VMEM_BUDGET // (8 * bn),
-                                         8)))
-    while kernel_vmem_bytes(p, bn, bp) > LOGISTIC_VMEM_BUDGET and bp > 8:
-        bp = aligned_fit_block(p, bp - 1)
+    """Largest lane-legal feature tile (a 128-multiple divisor of p, or
+    p itself) whose grid step fits the VMEM budget — bp = p (the
+    resident full-lane layout) whenever it fits. When none fits, the
+    smallest legal tile, which the routing predicate sends away."""
+    bp = p
+    while (p % LANE == 0 and bp > LANE
+           and kernel_vmem_bytes(p, bn, bp) > LOGISTIC_VMEM_BUDGET):
+        bp = lane_fit_block(p, bp - LANE)
     return bp
 
 
@@ -81,18 +82,19 @@ def resolve_logistic_blocks(n: int, p: int, block=None) -> Tuple[int, int]:
     `block` is None (bn = 128 request, bp budgeted), an int bn request,
     or an explicit (bn, bp) pair — e.g. an autotuned winner from
     `repro.kernels.autotune.autotune_logistic_block`. Each entry is
-    clipped to the largest 8-ALIGNED divisor of its dimension — the
-    tile the TPU grid can actually use, and the same notion of "legal"
-    the routing predicate judges by (a plain divisor scan can land on
-    alignment traps like 126 for size 504); a defaulted bp is the
-    largest such divisor whose slab fits `LOGISTIC_VMEM_BUDGET` (full
-    lanes for small p — the historical layout — feature tiles past it).
+    fitted to the TPU's (8, 128) tiling: bn, a sublane axis, to the
+    largest 8-ALIGNED divisor of n (a plain divisor scan can land on
+    alignment traps like 126 for size 504); bp, the lane axis, to the
+    largest 128-multiple divisor of p or the whole axis. A defaulted bp
+    is the largest such tile whose slab fits `LOGISTIC_VMEM_BUDGET`
+    (full lanes for small p — the historical layout — feature tiles
+    past it).
     """
     bn_req, bp_req = validate_block(block, 2, "(bn, bp)",
                                     arities=_BLOCK_ARITIES)
     bn = aligned_fit_block(n, 128 if bn_req is None else bn_req)
     bp = _budget_bp(p, bn) if bp_req is None \
-        else aligned_fit_block(p, bp_req)
+        else lane_fit_block(p, bp_req)
     return bn, bp
 
 
@@ -104,28 +106,22 @@ def _route_and_resolve(n: int, p: int,
     where reason is None on the kernel path, else the telemetry label
     for why the oracle won. Routed when: ragged axes (`ragged`);
     sample tiles degraded to slivers vs the request (e.g. n = 1016 =
-    8*127 against the 128 default) or an explicitly requested feature
-    tile that degrades the same way (`sliver`); a resolved tiling over
-    the per-tile VMEM budget — only p so large the gradient accumulator
-    outgrows it, by construction (`vmem_budget`); or a budgeted default
-    bp that itself collapsed to a sliver under the budget (p past the
-    full-lane regime with no mid-size aligned divisor, e.g. p = 8168 =
-    8*1021 resolves to bp = 8; also `sliver`). The clause SET is what
-    routes; the order only picks which label wins when several apply
-    (the over-budget p >= 16384 regime also collapses its default bp,
-    and `vmem_budget` is the informative cause)."""
-    bn_req, bp_req = validate_block(block, 2, "(bn, bp)",
-                                    arities=_BLOCK_ARITIES)
+    8*127 against the 128 default; `sliver`); or a resolved tiling over
+    the per-tile VMEM budget (`vmem_budget`) — p so large the gradient
+    accumulator outgrows it, or a p with no 128-multiple divisor (e.g.
+    p = 8168 = 8*1021) whose only legal feature tile, the whole axis,
+    does not fit. Lane tiles are 128-multiples or the whole axis, so the
+    feature axis never degrades to a sliver. The clause SET is what
+    routes; the order only picks which label wins when several
+    apply."""
+    bn_req, _ = validate_block(block, 2, "(bn, bp)", arities=_BLOCK_ARITIES)
     bn, bp = resolve_logistic_blocks(n, p, block)
     if is_ragged_samples(n, p):
         reason = "ragged"
-    elif (degrades_to_slivers(n, 128 if bn_req is None else bn_req)
-          or (bp_req is not None and degrades_to_slivers(p, bp_req))):
+    elif degrades_to_slivers(n, 128 if bn_req is None else bn_req):
         reason = "sliver"
     elif kernel_vmem_bytes(p, bn, bp) > LOGISTIC_VMEM_BUDGET:
         reason = "vmem_budget"
-    elif bp_req is None and bp < min(p, MIN_TILE):
-        reason = "sliver"
     else:
         reason = None
     return reason, bn, bp

@@ -12,9 +12,10 @@ from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
+from repro.kernels import common
 from repro.kernels.common import (
-    aligned_fit_block, degrades_to_slivers, is_ragged_samples, on_tpu,
-    record_route, validate_block,
+    aligned_fit_block, degrades_to_slivers, is_ragged_samples,
+    lane_fit_block, on_tpu, record_route, validate_block,
 )
 from repro.kernels.rank_update.kernel import (
     rank_update_pallas, rank_update_unfused_pallas,
@@ -48,21 +49,23 @@ def resolve_rank_blocks(n: int, p: int, block) -> Tuple[int, int]:
     winner from `repro.kernels.autotune.autotune_rank_block`; anything
     else raises instead of being silently coerced (the logistic
     dispatcher's old `block[0]` bug, audited here too). Each entry is
-    clipped to the largest 8-aligned divisor of its dimension, the same
-    notion of "legal" the routing predicate judges by."""
+    fitted to the TPU's (8, 128) tiling: bp, the lane axis of the X
+    slabs and the Sigma tile, to the largest 128-multiple divisor of p
+    or the whole axis; bn, a sublane axis, to the largest 8-aligned
+    divisor of n."""
     bp, bn = validate_block(block, 2, "(bp, bn)")
-    return aligned_fit_block(p, bp), aligned_fit_block(n, bn)
+    return lane_fit_block(p, bp), aligned_fit_block(n, bn)
 
 
 def _rank_route_reason(n: int, p: int, block=128) -> Optional[str]:
     """Routing verdict plus its telemetry label: None on the kernel
     path, else `ragged` / `sliver` / `vmem_budget` (same clause set as
     ever; the order only picks the label when several apply)."""
-    bp_req, bn_req = validate_block(block, 2, "(bp, bn)")
+    _, bn_req = validate_block(block, 2, "(bp, bn)")
     bp, bn = resolve_rank_blocks(n, p, block)
     if is_ragged_samples(n, p):
         return "ragged"
-    if degrades_to_slivers(n, bn_req) or degrades_to_slivers(p, bp_req):
+    if degrades_to_slivers(n, bn_req):
         return "sliver"
     if rank_vmem_bytes(bp, bn) > RANK_VMEM_BUDGET:
         return "vmem_budget"
@@ -71,11 +74,12 @@ def _rank_route_reason(n: int, p: int, block=128) -> Optional[str]:
 
 def rank_routes_to_oracle(n: int, p: int, block=128) -> bool:
     """Routing predicate shared with the engine's rank block policy:
-    ragged shapes, shapes whose requested tiles degrade to sliver grids
-    (e.g. n = 1016 against a 128 request), and resolved tilings whose
-    grid step busts `RANK_VMEM_BUDGET` (an explicit block= large enough
-    that the X slabs or the Sigma tile outgrow VMEM) go to the jnp
-    oracle."""
+    ragged shapes, shapes whose requested sample tiles degrade to
+    sliver grids (e.g. n = 1016 against a 128 request), and resolved
+    tilings whose grid step busts `RANK_VMEM_BUDGET` (an explicit
+    block= large enough that the X slabs or the Sigma tile outgrow
+    VMEM, or a p with no 128-multiple divisor whose whole-axis tile
+    does) go to the jnp oracle."""
     return _rank_route_reason(n, p, block) is not None
 
 
@@ -96,7 +100,7 @@ def rank_update(Xs, ys, weights=None, *, block=128,
     # a malformed block must raise on every path, not only on TPU
     bp, bn = resolve_rank_blocks(n, p, block)
     if use_kernel is None:
-        use_kernel = on_tpu()
+        use_kernel = common.kernels_by_default()
     interp = (not on_tpu()) if interpret is None else interpret
     reason = _rank_route_reason(n, p, block)
     if not use_kernel or reason is not None:

@@ -36,8 +36,8 @@ code RL108). Dispatch-time decisions that genuinely happen at trace
 time (kernel routing, autotune cache events, collective byte models)
 funnel through audited helpers — `kernels.common.record_route`,
 `substrate.collectives` — that record only Python-concrete values;
-everything else records eagerly, guarded by
-`jax.core.trace_state_clean` at the call site.
+everything else records eagerly, skipping traced values
+(`isinstance(x, jax.core.Tracer)`) at the call site.
 """
 from __future__ import annotations
 

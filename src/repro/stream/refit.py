@@ -12,6 +12,11 @@ warm/cold gap `benchmarks/stream_bench.py` measures.
 `RefitInfo.jaccard` reports support drift against the previous
 generation so callers can refit lazily: an unchanged support (jaccard
 == 1) means the served model has not moved and the next refit can wait.
+
+On a mesh the two solves run per task shard inside `shard_map`: their
+FISTA steps are Pallas kernels on TPU, which the SPMD partitioner
+cannot split, and the tasks are independent, so each device solves its
+own tasks' stacks and nothing is gathered.
 """
 from __future__ import annotations
 
@@ -20,6 +25,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.engine import (
     debias_batched, inverse_hessian_batched, power_iteration_batched,
@@ -28,6 +34,7 @@ from repro.core.engine import (
 from repro.core.logistic import debias_logistic_batched
 from repro.core.prox import support_from_rows
 from repro.stream.state import StreamState
+from repro.substrate import shard_map
 
 
 class RefitInfo(NamedTuple):
@@ -47,10 +54,55 @@ def jaccard_support(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(union > 0, inter / jnp.maximum(union, 1), 1.0)
 
 
-@partial(jax.jit, static_argnames=("lasso_iters", "debias_iters", "warm"))
+def _solves(Sigmas, cs, lam, mu, beta0, M0, tol, *, lasso_iters: int,
+            debias_iters: int):
+    """Steps 1-2 of Algorithm 1 for a batch of tasks: the lasso and the
+    debias M solve, sharing one power iteration. Returns (beta_hat, Ms,
+    lasso iterations run, debias iterations run)."""
+    lam_max = power_iteration_batched(Sigmas)
+    beta_hat, lasso_run = solve_lasso_eq2(
+        Sigmas, cs, lam, iters=lasso_iters, beta0=beta0, lam_max=lam_max,
+        tol=tol, return_iters=True)
+    Ms, debias_run = inverse_hessian_batched(
+        Sigmas, mu, iters=debias_iters, M0=M0, lam_max=lam_max, tol=tol,
+        return_iters=True)
+    return beta_hat, Ms, lasso_run, debias_run
+
+
+def _sharded_solves(mesh, task_axis, Sigmas, cs, lam, mu, beta0, M0, tol,
+                    **iters):
+    """`_solves` per task shard of `mesh`. A cold refit's starts are
+    spelled out (zeros; the engine's scaled identity, which is
+    symmetric) so every operand has a task axis to shard, and each
+    shard's iteration counts come back as one entry per device (its
+    while loops exit on their own residuals); the largest is
+    reported."""
+    if beta0 is None:
+        beta0 = jnp.zeros_like(cs)
+    if M0 is None:
+        M0 = scaled_identity_m0(Sigmas)
+    T, R, each = P(task_axis), P(), P(tuple(mesh.axis_names))
+    # lam, mu and tol are scalars, replicated; tol=None (fixed budgets)
+    # stays a Python None
+    scalars = [jnp.asarray(v, cs.dtype) for v in (lam, mu)
+               + (() if tol is None else (tol,))]
+
+    def local(S, c, b0, m0, lam_, mu_, tol_=None):
+        b, M, nl, nd = _solves(S, c, lam_, mu_, b0, m0, tol_, **iters)
+        return b, M, jnp.reshape(nl, (1,)), jnp.reshape(nd, (1,))
+
+    beta_hat, Ms, nl, nd = shard_map(
+        local, mesh=mesh, in_specs=(T, T, T, T) + (R,) * len(scalars),
+        out_specs=(T, T, each, each))(Sigmas, cs, beta0, M0, *scalars)
+    return beta_hat, Ms, jnp.max(nl), jnp.max(nd)
+
+
+@partial(jax.jit, static_argnames=("lasso_iters", "debias_iters", "warm",
+                                   "mesh", "task_axis"))
 def refit(state: StreamState, lam, mu, Lam, lasso_iters: int = 400,
-          debias_iters: int = 600, warm: bool = True,
-          tol=None) -> Tuple[StreamState, RefitInfo]:
+          debias_iters: int = 600, warm: bool = True, tol=None,
+          mesh=None, task_axis: str = "task"
+          ) -> Tuple[StreamState, RefitInfo]:
     """One DSML refresh on the state's statistics.
 
     Returns the new state (updated beta/M/support, generation + 1) and
@@ -67,19 +119,25 @@ def refit(state: StreamState, lam, mu, Lam, lasso_iters: int = 400,
     budget the serving front relies on to keep refits off the predict
     path. The iterations run come back on the info
     (`lasso_iters_run`/`debias_iters_run`).
+
+    With a `mesh`, the solves run per shard of its `task_axis` (the
+    statistics are task-sharded there, replicated over the data axis);
+    everything after them — debias, threshold, drift — is partitioned
+    by XLA.
     """
     beta0 = state.beta_local if warm else None
     M0 = None
     if warm:
         M0 = jnp.where(state.generation > 0, state.Ms,
                        scaled_identity_m0(state.Sigmas))
-    lam_max = power_iteration_batched(state.Sigmas)
-    beta_hat, lasso_run = solve_lasso_eq2(
-        state.Sigmas, state.cs, lam, iters=lasso_iters, beta0=beta0,
-        lam_max=lam_max, tol=tol, return_iters=True)
-    Ms, debias_run = inverse_hessian_batched(
-        state.Sigmas, mu, iters=debias_iters, M0=M0, lam_max=lam_max,
-        tol=tol, return_iters=True)
+    iters = dict(lasso_iters=lasso_iters, debias_iters=debias_iters)
+    if mesh is None:
+        beta_hat, Ms, lasso_run, debias_run = _solves(
+            state.Sigmas, state.cs, lam, mu, beta0, M0, tol, **iters)
+    else:
+        beta_hat, Ms, lasso_run, debias_run = _sharded_solves(
+            mesh, task_axis, state.Sigmas, state.cs, lam, mu, beta0, M0,
+            tol, **iters)
     beta_u = debias_batched(state.Sigmas, state.cs, beta_hat, Ms)
     support = support_from_rows(beta_u.T, Lam)
     beta_tilde = beta_u * support[None, :]

@@ -308,10 +308,13 @@ class StreamingDsmlService:
                 esc = min(2 ** self._refit_failures, MAX_ITER_ESCALATION)
                 l_iters = self.lasso_iters * esc
                 d_iters = self.debias_iters * esc
+            # on a mesh the solves run per task shard (stream/refit.py)
+            sharded = {} if self.mesh is None else \
+                {"mesh": self.mesh, "task_axis": self.task_axis}
             candidate, info = self._refit_impl(
                 self.state, self.lam, self.mu, self.Lam,
                 lasso_iters=l_iters, debias_iters=d_iters, warm=warm,
-                tol=self.refit_tol)
+                tol=self.refit_tol, **sharded)
             if self.refit_health_checks:
                 health = refit_health(candidate, self.lam,
                                       kkt_ceiling=self.refit_kkt_ceiling,

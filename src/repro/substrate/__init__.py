@@ -10,7 +10,9 @@ from repro.substrate.collectives import (
 )
 from repro.substrate.compat import make_mesh, shard_map, use_mesh
 from repro.substrate.feed import chunk_specs, feed_chunk, feed_shards
-from repro.substrate.hostenv import force_host_device_count, host_device_env
+from repro.substrate.hostenv import (
+    enable_compile_cache, force_host_device_count, host_device_env,
+)
 from repro.substrate.mesh import data_model_mesh, data_task_mesh, task_mesh
 from repro.substrate.probes import REPO_ROOT, popen_probe, run_probe
 
@@ -18,7 +20,7 @@ __all__ = [
     "all_gather_tasks", "all_to_all_experts", "psum_stats",
     "make_mesh", "shard_map", "use_mesh",
     "chunk_specs", "feed_chunk", "feed_shards",
-    "force_host_device_count", "host_device_env",
+    "enable_compile_cache", "force_host_device_count", "host_device_env",
     "data_model_mesh", "data_task_mesh", "task_mesh",
     "REPO_ROOT", "popen_probe", "run_probe",
 ]

@@ -1,11 +1,8 @@
-"""Version-portable jax distributed API resolution.
+"""The jax distributed API as this repo uses it.
 
-jax has moved `shard_map` twice (`jax.experimental.shard_map` ->
-`jax.shard_map`) and renamed its replication-check kwarg
-(`check_rep` -> `check_vma`); the ambient-mesh context manager has
-likewise wandered (`Mesh.__enter__` -> `jax.sharding.use_mesh` ->
-`jax.set_mesh`). Every caller in this repo goes through the resolvers
-here instead of hard-coding one vintage of the API.
+Every caller in this repo builds meshes and shard_maps through the
+wrappers here, so the settings they fix (no replication check, `Auto`
+mesh axes) hold everywhere.
 
 Nothing in this module touches jax device state at import time, so it is
 safe to import before `force_host_device_count` (see `hostenv.py`).
@@ -13,63 +10,31 @@ safe to import before `force_host_device_count` (see `hostenv.py`).
 from __future__ import annotations
 
 import contextlib
-import inspect
-from typing import Any, Callable
+from typing import Callable
 
 import jax
-
-
-def _resolve_shard_map() -> Callable[..., Any]:
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map as sm  # jax <= 0.5
-    return sm
-
-
-_RAW_SHARD_MAP = _resolve_shard_map()
-# name of the replication-check kwarg on the installed jax, if any
-_CHECK_KW = next(
-    (kw for kw in ("check_vma", "check_rep")
-     if kw in inspect.signature(_RAW_SHARD_MAP).parameters),
-    None,
-)
+from jax.sharding import AxisType
 
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs, check: bool = False):
-    """`jax.shard_map` with the replication check spelled portably.
-
-    `check=False` maps to `check_vma=False` on new jax and
-    `check_rep=False` on 0.4.x/0.5.x; the kwarg is omitted entirely on a
-    jax that dropped it.
-    """
-    kwargs = {_CHECK_KW: check} if _CHECK_KW is not None else {}
-    return _RAW_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
+    """`jax.shard_map` with the replication check (`check_vma`) off by
+    default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def make_mesh(shape, axis_names):
-    """`jax.make_mesh` where available, mesh_utils otherwise."""
-    mk = getattr(jax, "make_mesh", None)
-    if mk is not None:
-        return mk(tuple(shape), tuple(axis_names))
-    from jax.experimental import mesh_utils
-    devices = mesh_utils.create_device_mesh(tuple(shape))
-    return jax.sharding.Mesh(devices, tuple(axis_names))
+    """`jax.make_mesh` with every axis `Auto`: the SPMD partitioner
+    propagates shardings through the jitted programs. On jax 0.9 the
+    default is `Explicit`, under which the sharded ingest's `select`
+    refuses operands of differing shardings."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 @contextlib.contextmanager
 def use_mesh(mesh):
-    """Ambient-mesh context manager across jax versions.
-
-    Prefers `jax.set_mesh` / `jax.sharding.use_mesh`; falls back to the
-    legacy `with mesh:` block on 0.4.x.
-    """
-    setter = getattr(jax, "set_mesh", None) or \
-        getattr(jax.sharding, "use_mesh", None)
-    if setter is not None:
-        with setter(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    """Ambient-mesh context manager (`jax.set_mesh`) that yields the
+    mesh."""
+    with jax.set_mesh(mesh):
+        yield mesh

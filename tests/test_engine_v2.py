@@ -437,12 +437,26 @@ def test_explicit_block_bypasses_autotune(monkeypatch):
 
 
 def test_autotuned_default_policy_on_kernel_path(tmp_path, monkeypatch):
+    """block=None on the kernel path LOOKS the autotuned winner up: on a
+    cold cache it serves the deterministic default without sweeping
+    (the policy runs under jit traces); once an eager sweep has filled
+    the cache, the solve takes the winner."""
     monkeypatch.setenv("REPRO_CACHE_DIR", str(tmp_path))
     from repro.kernels import autotune
     autotune.clear_memory_cache()
+    served = []
+    orig = autotune.autotune_block
+    monkeypatch.setattr(autotune, "autotune_block", lambda *a, **k: (
+        served.append(orig(*a, **k)), served[-1])[1])
     Sigmas, cs = _reg_stats(m=2, p=32)
-    out = solve_lasso_batched(Sigmas, cs, 0.1, iters=30, use_kernel=True,
-                              interpret=True)       # block=None -> autotune
     ref = solve_lasso_batched(Sigmas, cs, 0.1, iters=30)
+    out = solve_lasso_batched(Sigmas, cs, 0.1, iters=30, use_kernel=True,
+                              interpret=True)       # block=None -> lookup
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    assert not autotune.cache_path().exists()       # a miss never sweeps
+    winner = orig(2, 32, 1, reps=1)                 # the eager sweep
     assert autotune.cache_path().exists()
+    out = solve_lasso_batched(Sigmas, cs, 0.1, iters=30, use_kernel=True,
+                              interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    assert served[-1] == winner

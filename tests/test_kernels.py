@@ -201,10 +201,14 @@ def test_rank_and_ista_block_validation_raises():
 def test_ista_resolve_blocks_no_sliver_halving():
     """The old local halving clip degraded non-divisor requests to
     single-element tiles (48-on-80 -> 1); the aligned divisor scan
-    returns 40."""
+    returns 40 for the sublane tile bp, and the lane tiles (bk, and br
+    past r = 1) take a 128-multiple divisor or the whole axis — the
+    only tiles the TPU compiler accepts there."""
     from repro.kernels.ista_step.ops import resolve_blocks
-    assert resolve_blocks(80, 1, 48) == (40, 1, 40)
+    assert resolve_blocks(80, 1, 48) == (40, 1, 80)
     assert resolve_blocks(384, 8, 128) == (128, 8, 128)
+    assert resolve_blocks(200, 200, 128) == (40, 200, 200)
+    assert resolve_blocks(1024, 1024, 64) == (64, 128, 128)
 
 
 def test_sliver_shapes_route_to_oracle_bitwise():
@@ -224,12 +228,11 @@ def test_sliver_shapes_route_to_oracle_bitwise():
     assert not degrades_to_slivers(80, 48)  # modest clip stays on-kernel
     assert not degrades_to_slivers(1016, 8)  # explicit tiny request honoured
     assert routes_to_oracle(1016, 64) and rank_routes_to_oracle(1016, 64)
-    # the budgeted DEFAULT bp can degrade too: p = 8168 = 8*1021 is past
-    # the full-lane budget but has no mid-size aligned divisor, so the
-    # default policy resolves bp = 8 — a sliver sweep that must route
-    # away just like an explicit sliver request would
+    # p = 8168 = 8*1021 is past the full-lane budget and has no
+    # 128-multiple divisor, so its only legal feature tile is the whole
+    # axis, which busts the budget: it must route away
     from repro.kernels.logistic_grad.ops import resolve_logistic_blocks
-    assert resolve_logistic_blocks(128, 8168)[1] == 8
+    assert resolve_logistic_blocks(128, 8168)[1] == 8168
     assert routes_to_oracle(128, 8168)
     assert not routes_to_oracle(128, 8192)   # aligned divisors: on-kernel
 

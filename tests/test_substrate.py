@@ -212,3 +212,21 @@ def test_smoke_configs_are_reduced():
         assert cfg.d_model <= 512
         if cfg.moe:
             assert cfg.moe.n_experts <= 4
+
+
+# ---------------------------------------------------------------------------
+# subprocess probes
+# ---------------------------------------------------------------------------
+
+def test_run_probe_child_stays_on_cpu(monkeypatch):
+    """Probe children are CPU host-device probes by definition: even
+    when the parent's environment names an accelerator platform, the
+    child gets JAX_PLATFORMS=cpu and never reaches for a chip the
+    parent may hold."""
+    from repro.substrate import host_device_env, run_probe
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert host_device_env(2)["JAX_PLATFORMS"] == "cpu"
+    res = run_probe("import jax; print(jax.devices()[0].platform, "
+                    "jax.device_count())", n_devices=2, timeout=300)
+    assert res.returncode == 0, res.stderr[-2000:]
+    assert res.stdout.split() == ["cpu", "2"]

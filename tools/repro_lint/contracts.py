@@ -15,7 +15,9 @@ Two passes, neither of which needs a TPU or executes a kernel:
   `LOGISTIC_VMEM_BUDGET`, `rank_vmem_bytes`, `aligned_fit_block`) and
   evaluates them over an adversarial shape×block grid: every
   configuration the predicate lets through to the kernel must resolve
-  to 8-aligned divisor tiles (RL211) inside the kernel's VMEM budget
+  to divisor tiles that obey the TPU's (8, 128) rule — a multiple of
+  128 or the whole axis on lanes, 8-aligned or the whole axis on
+  sublanes (RL211) — inside the kernel's VMEM budget
   (RL210), the predicate and the resolver must agree with the
   dispatcher's own fused route-and-resolve path (RL212), and every
   tiling the autotuner would sweep must be one the dispatcher will
@@ -191,6 +193,10 @@ def _aligned_divisor(size: int, tile: int) -> bool:
     return size % tile == 0 and (tile % 8 == 0 or tile == size)
 
 
+def _lane_divisor(size: int, tile: int) -> bool:
+    return size % tile == 0 and (tile % 128 == 0 or tile == size)
+
+
 def check_logistic_contract() -> List[Finding]:
     from repro.kernels.logistic_grad.ops import (
         LOGISTIC_VMEM_BUDGET, _route_and_resolve, kernel_vmem_bytes,
@@ -212,7 +218,7 @@ def check_logistic_contract() -> List[Finding]:
                 if reason is not None:
                     continue
                 if not (_aligned_divisor(n, bn)
-                        and _aligned_divisor(p, bp)):
+                        and _lane_divisor(p, bp)):
                     findings.append(Finding(
                         rel, 0, "RL211",
                         f"dispatchable (n={n}, p={p}, block={block}) "
@@ -262,7 +268,7 @@ def check_rank_contract() -> List[Finding]:
                 if rank_routes_to_oracle(n, p, block):
                     continue
                 bp, bn = resolve_rank_blocks(n, p, block)
-                if not (_aligned_divisor(p, bp)
+                if not (_lane_divisor(p, bp)
                         and _aligned_divisor(n, bn)):
                     findings.append(Finding(
                         rel, 0, "RL211",
@@ -288,16 +294,19 @@ def check_rank_contract() -> List[Finding]:
 
 def check_solver_contract() -> List[Finding]:
     from repro.kernels.autotune import block_candidates
-    from repro.kernels.ista_step.ops import is_ragged, resolve_blocks
+    from repro.kernels.ista_step.ops import (
+        resolve_blocks, step_routes_to_oracle,
+    )
     rel = "src/repro/kernels/ista_step/ops.py"
     findings: List[Finding] = []
     for p in SOLVER_P:
         for r in SOLVER_R:
-            if is_ragged(p, r):
-                continue
             for block in SOLVER_BLOCKS + tuple(block_candidates(p, r)):
+                if step_routes_to_oracle(p, r, block):
+                    continue
                 bp, br, bk = resolve_blocks(p, r, block)
-                ok = (p % bp == 0 and r % br == 0 and p % bk == 0)
+                ok = (_aligned_divisor(p, bp) and _lane_divisor(r, br)
+                      and _lane_divisor(p, bk))
                 if not ok:
                     findings.append(Finding(
                         rel, 0, "RL211",
