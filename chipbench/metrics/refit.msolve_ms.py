@@ -1,0 +1,7 @@
+"""Device time of the refit's M solve (`refit.msolve` ops, `phases.py`)
+per refit in the window."""
+from chipbench import phases
+
+
+def read(ctx):
+    return phases.per_refit_ms(ctx, "refit.msolve")

@@ -16,7 +16,11 @@ Semantics (DESIGN.md §14):
   serving front's p50/p99 come from `hist_quantiles`, computed over
   the retained window, without bucket configuration), and **spans**
   time a `with` block on the monotonic clock, recording both a
-  `<name>.ms` histogram observation and a Chrome trace event.
+  `<name>.ms` histogram observation and a Chrome trace event. When an
+  annotation hook is installed (`set_annotation_hook`;
+  `repro.obs.jaxprof.annotate_spans` installs the `jax.profiler` one),
+  each span also opens the hook's context under its name, so the span
+  lands on the profiler's clock beside the device's ops.
 * Every metric takes free-form keyword **labels**; a (name, labels)
   pair is one series. Labels must be low-cardinality Python scalars
   (kernel names, route reasons, axis names — never array values).
@@ -45,7 +49,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 # cap on buffered Chrome trace events; past it, events are dropped and
 # counted (a long-running service must not grow a timeline unbounded)
@@ -58,6 +62,11 @@ MAX_TRACE_EVENTS = 65536
 HIST_SAMPLE_CAP = 4096
 
 MetricKey = Tuple[str, Tuple[Tuple[str, Any], ...]]
+
+# called with a span's name as it opens; returns a context manager to
+# hold open for the span (or None). Installed process-wide by
+# `set_annotation_hook`, since a profiler trace is process-wide too.
+_annotation_hook: Optional[Callable[[str], Any]] = None
 
 
 def _env_enabled() -> bool:
@@ -121,7 +130,7 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_reg", "_name", "_labels", "_t0")
+    __slots__ = ("_reg", "_name", "_labels", "_t0", "_ann")
 
     def __init__(self, reg: "Registry", name: str, labels: dict) -> None:
         self._reg = reg
@@ -129,11 +138,17 @@ class _Span:
         self._labels = labels
 
     def __enter__(self) -> "_Span":
+        hook = _annotation_hook
+        self._ann = hook(self._name) if hook is not None else None
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         dur_ns = time.perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         self._reg._finish_span(self._name, self._labels, self._t0, dur_ns)
         return False
 
@@ -204,18 +219,12 @@ class Registry:
         """Context manager timing its block on the monotonic clock. On
         exit records a `<name>.ms` histogram observation and buffers a
         Chrome trace event ("X" phase, microsecond timestamps) carrying
-        `labels` as the event args."""
+        `labels` as the event args. With an annotation hook installed,
+        the block also runs inside the hook's context for `name` (the
+        labels are not passed: nothing is formatted per span)."""
         if not self._enabled:
             return _NULL_SPAN
         return _Span(self, name, labels)
-
-    def event(self, name: str, ts_us: float, dur_us: float,
-              **labels) -> None:
-        """Buffer an explicit Chrome trace event (e.g. reconstructed
-        from an external timing) without the histogram side effect."""
-        if not self._enabled:
-            return
-        self._push_event(name, labels, ts_us, dur_us)
 
     def _finish_span(self, name: str, labels: dict, t0_ns: int,
                      dur_ns: int) -> None:
@@ -326,6 +335,16 @@ _REGISTRY = Registry(enabled=_env_enabled())
 
 def get_registry() -> Registry:
     return _REGISTRY
+
+
+def set_annotation_hook(hook: Optional[Callable[[str], Any]]) -> None:
+    """Install (or with None remove) the hook every enabled span calls
+    with its name as it opens. The hook returns a context manager that
+    the span holds open until it closes, or None to add nothing; it is
+    called once per span, so it must be cheap when it has nothing to
+    do. Disabled registries never call it."""
+    global _annotation_hook
+    _annotation_hook = hook
 
 
 def enabled() -> bool:

@@ -59,13 +59,16 @@ def _solves(Sigmas, cs, lam, mu, beta0, M0, tol, *, lasso_iters: int,
     """Steps 1-2 of Algorithm 1 for a batch of tasks: the lasso and the
     debias M solve, sharing one power iteration. Returns (beta_hat, Ms,
     lasso iterations run, debias iterations run)."""
-    lam_max = power_iteration_batched(Sigmas)
-    beta_hat, lasso_run = solve_lasso_eq2(
-        Sigmas, cs, lam, iters=lasso_iters, beta0=beta0, lam_max=lam_max,
-        tol=tol, return_iters=True)
-    Ms, debias_run = inverse_hessian_batched(
-        Sigmas, mu, iters=debias_iters, M0=M0, lam_max=lam_max, tol=tol,
-        return_iters=True)
+    with jax.named_scope("refit.power"):
+        lam_max = power_iteration_batched(Sigmas)
+    with jax.named_scope("refit.lasso"):
+        beta_hat, lasso_run = solve_lasso_eq2(
+            Sigmas, cs, lam, iters=lasso_iters, beta0=beta0,
+            lam_max=lam_max, tol=tol, return_iters=True)
+    with jax.named_scope("refit.msolve"):
+        Ms, debias_run = inverse_hessian_batched(
+            Sigmas, mu, iters=debias_iters, M0=M0, lam_max=lam_max,
+            tol=tol, return_iters=True)
     return beta_hat, Ms, lasso_run, debias_run
 
 
@@ -124,12 +127,18 @@ def refit(state: StreamState, lam, mu, Lam, lasso_iters: int = 400,
     statistics are task-sharded there, replicated over the data axis);
     everything after them — debias, threshold, drift — is partitioned
     by XLA.
+
+    Each phase runs under a `jax.named_scope` — `refit.power`,
+    `refit.lasso`, `refit.msolve` (with its warm start), `refit.debias`,
+    `refit.threshold` — so the compiled program's ops carry their phase
+    in their `op_name` metadata, and a profile can be split by phase.
     """
     beta0 = state.beta_local if warm else None
     M0 = None
     if warm:
-        M0 = jnp.where(state.generation > 0, state.Ms,
-                       scaled_identity_m0(state.Sigmas))
+        with jax.named_scope("refit.msolve"):
+            M0 = jnp.where(state.generation > 0, state.Ms,
+                           scaled_identity_m0(state.Sigmas))
     iters = dict(lasso_iters=lasso_iters, debias_iters=debias_iters)
     if mesh is None:
         beta_hat, Ms, lasso_run, debias_run = _solves(
@@ -138,18 +147,22 @@ def refit(state: StreamState, lam, mu, Lam, lasso_iters: int = 400,
         beta_hat, Ms, lasso_run, debias_run = _sharded_solves(
             mesh, task_axis, state.Sigmas, state.cs, lam, mu, beta0, M0,
             tol, **iters)
-    beta_u = debias_batched(state.Sigmas, state.cs, beta_hat, Ms)
-    support = support_from_rows(beta_u.T, Lam)
-    beta_tilde = beta_u * support[None, :]
-    new_state = state._replace(
-        beta_local=beta_hat, Ms=Ms, beta_u=beta_u, beta_tilde=beta_tilde,
-        support=support, generation=state.generation + 1)
-    info = RefitInfo(
-        jaccard=jaccard_support(support, state.support).astype(state.cs.dtype),
-        support_size=jnp.sum(support).astype(jnp.int32),
-        generation=new_state.generation,
-        lasso_iters_run=jnp.asarray(lasso_run, jnp.int32),
-        debias_iters_run=jnp.asarray(debias_run, jnp.int32))
+    with jax.named_scope("refit.debias"):
+        beta_u = debias_batched(state.Sigmas, state.cs, beta_hat, Ms)
+    with jax.named_scope("refit.threshold"):
+        support = support_from_rows(beta_u.T, Lam)
+        beta_tilde = beta_u * support[None, :]
+        new_state = state._replace(
+            beta_local=beta_hat, Ms=Ms, beta_u=beta_u,
+            beta_tilde=beta_tilde, support=support,
+            generation=state.generation + 1)
+        info = RefitInfo(
+            jaccard=jaccard_support(support, state.support).astype(
+                state.cs.dtype),
+            support_size=jnp.sum(support).astype(jnp.int32),
+            generation=new_state.generation,
+            lasso_iters_run=jnp.asarray(lasso_run, jnp.int32),
+            debias_iters_run=jnp.asarray(debias_run, jnp.int32))
     return new_state, info
 
 

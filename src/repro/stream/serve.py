@@ -28,11 +28,11 @@ Two pieces, separable on purpose:
   batch-mates were never mixed across a refit.
 
 Telemetry (all eager, worker-thread side — never under jit, RL108):
-`serve.queue_depth` gauge at each drain, `serve.batch_fill` and
-`serve.batch_rows` histograms, a `serve.batch` span around the
-dispatch, `serve.request_ms` per-request enqueue-to-result latency
-(p50/p99 via `obs.hist_quantiles`), and `serve.requests` / `serve.rows`
-/ `serve.batches` / `serve.errors` counters.
+`serve.queue_depth` gauge at each drain, a `serve.batch_rows`
+histogram, a `serve.batch` span around the dispatch and the wait for
+its scores, per request `serve.queue_ms` (enqueue to its batch's
+dispatch) and `serve.request_ms` (enqueue to result; p50/p99 via
+`obs.hist_quantiles`), and `serve.batches` / `serve.errors` counters.
 """
 from __future__ import annotations
 
@@ -282,6 +282,7 @@ class ServingFront:
         for req in batch:
             X[off:off + req.X.shape[0]] = req.X
             off += req.X.shape[0]
+        t_dispatch = time.perf_counter()
         with obs.span("serve.batch", rows=rows, padded=padded):
             scores = np.asarray(
                 _predict_shared(snap.beta_tilde, jnp.asarray(X)))
@@ -293,13 +294,12 @@ class ServingFront:
                 scores=scores[:, off:off + n_i],
                 generation=snap.generation))
             off += n_i
+            obs.observe("serve.queue_ms",
+                        (t_dispatch - req.t_enqueue) * 1e3)
             obs.observe("serve.request_ms",
                         (t_done - req.t_enqueue) * 1e3)
         obs.inc("serve.batches")
-        obs.inc("serve.requests", len(batch))
-        obs.inc("serve.rows", rows)
         obs.observe("serve.batch_rows", rows)
-        obs.observe("serve.batch_fill", rows / self.max_batch)
 
     def _drain_remaining(self) -> List[_Request]:
         """Non-blocking gather for the worker's final sweep: carry slot

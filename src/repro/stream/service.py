@@ -54,6 +54,7 @@ from repro.checkpoint.io import (
     CheckpointError, load_npz, npz_safe_dtype, restore_pytree, save_pytree,
 )
 from repro.checkpoint.manifest import CheckpointStore
+from repro.obs import jaxprof
 from repro.stream.accumulate import ingest_sharded
 from repro.stream.guard import IngestGuard, _guarded_fold
 from repro.stream.health import RefitHealth, refit_health
@@ -64,6 +65,10 @@ from repro.stream.state import (
     StreamState, init_stream_state, init_window, ingest, window_ingest,
     window_stats,
 )
+
+# the service's spans also go into any active jax.profiler trace, on
+# the clock of the device's ops (DESIGN.md §14)
+jaxprof.annotate_spans()
 
 # consecutive-failure escalation of the retry iteration budget is
 # capped: past 2 failures more iterations stop being the cure and the
@@ -215,10 +220,16 @@ class StreamingDsmlService:
         the chunk — a rejected chunk neither folds nor advances the
         refit cadence, so `(Sigma, c)` stay bitwise unchanged).
 
-        The `stream.ingest` span times the host-side fold DISPATCH
-        (the jitted fold is asynchronous — rows/sec headlines from it
-        are an upper bound on sustained throughput); a triggered refit
-        is timed by its own `stream.refit` span, not this one.
+        The `stream.ingest` span covers the fold; a triggered refit is
+        timed by its own `stream.refit` span, not this one. On the
+        default guarded path it is TRUE latency, ending after the fold
+        has run on the device: `stream.ingest.fold` times the call that
+        hands the chunk to the fold (on a TPU it returns before the
+        chunk's copy to the device is done), and `stream.ingest.guard`
+        the wait for the fold's health probe, which covers the rest of
+        the copy and the fold. On the other paths (no guard, a window,
+        a mesh, an absolute `max_abs` ceiling) it times the
+        asynchronous dispatch only.
         """
         # dense host path: probe fused into the fold dispatch (one
         # launch, one sync — the <2% overhead contract); window/sharded
@@ -235,11 +246,13 @@ class StreamingDsmlService:
         n = int(X_batch.shape[1])
         with obs.span("stream.ingest"):
             if fused:
-                folded, health = _guarded_fold(
-                    self.state, X_batch, y_batch, self.decay)
-                ok, _reason = self.guard.record(
-                    np.asarray(health),
-                    tuple(int(s) for s in X_batch.shape))
+                with obs.span("stream.ingest.fold"):
+                    folded, health = _guarded_fold(
+                        self.state, X_batch, y_batch, self.decay)
+                with obs.span("stream.ingest.guard"):
+                    ok, _reason = self.guard.record(
+                        np.asarray(health),
+                        tuple(int(s) for s in X_batch.shape))
                 if not ok:
                     # the speculative fold is discarded unassigned:
                     # (Sigma, c) stay bitwise the pre-chunk arrays
@@ -285,9 +298,12 @@ class StreamingDsmlService:
         2^failures, capped at x4). The returned `RefitInfo` then
         describes the KEPT state (unchanged generation, jaccard 1.0).
 
-        The `stream.refit` span is TRUE latency (unlike the async
-        ingest span): the health verdict and drift read block on the
-        refreshed model inside the span.
+        The `stream.refit` span is TRUE latency: the health verdict and
+        drift read block on the refreshed model inside the span. Its
+        children split it: `stream.refit.solve` times the refit's
+        dispatch (and its compilation, the first time), and
+        `stream.refit.health` the health check, which waits for the
+        candidate to be computed.
         """
         with obs.span("stream.refit"):
             if self.window is not None and int(self.window.seen) > 0:
@@ -311,14 +327,17 @@ class StreamingDsmlService:
             # on a mesh the solves run per task shard (stream/refit.py)
             sharded = {} if self.mesh is None else \
                 {"mesh": self.mesh, "task_axis": self.task_axis}
-            candidate, info = self._refit_impl(
-                self.state, self.lam, self.mu, self.Lam,
-                lasso_iters=l_iters, debias_iters=d_iters, warm=warm,
-                tol=self.refit_tol, **sharded)
+            with obs.span("stream.refit.solve"):
+                candidate, info = self._refit_impl(
+                    self.state, self.lam, self.mu, self.Lam,
+                    lasso_iters=l_iters, debias_iters=d_iters, warm=warm,
+                    tol=self.refit_tol, **sharded)
             if self.refit_health_checks:
-                health = refit_health(candidate, self.lam,
-                                      kkt_ceiling=self.refit_kkt_ceiling,
-                                      max_support=self.max_support)
+                with obs.span("stream.refit.health"):
+                    health = refit_health(
+                        candidate, self.lam,
+                        kkt_ceiling=self.refit_kkt_ceiling,
+                        max_support=self.max_support)
             else:
                 health = RefitHealth(True, None, float("nan"), -1)
             self.last_health = health
@@ -451,7 +470,6 @@ class StreamingDsmlService:
                 out = _predict_shared(snap.beta_tilde, X)
             else:
                 out = _predict_tasks(snap.beta_tilde, X)
-        obs.inc("stream.predict.requests")
         obs.inc("stream.predict.rows", int(X.shape[-2]))
         return (out, snap.generation) if return_generation else out
 

@@ -100,10 +100,35 @@ def test_disabled_registry_is_inert():
     assert snap["gauges"] == [] and reg.trace_events() == []
 
 
+def test_span_lands_in_the_profiler_trace(tmp_path):
+    """With the jax.profiler hook on, a span is also an event named after
+    it on the profiler's host plane (its labels stay out of the name); a
+    disabled registry puts nothing there; with no trace active the hook
+    opens nothing."""
+    from jax.profiler import ProfileData
+
+    from repro.obs import jaxprof, registry
+    jaxprof.annotate_spans()
+    assert registry._annotation_hook("x.idle") is None
+    off = Registry(enabled=False)
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("x.y", label="kept"):
+            pass
+        with off.span("x.off"):
+            pass
+    path, = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    host = ProfileData.from_file(str(path)).find_plane_with_name("/host:CPU")
+    names = [ev.name for line in host.lines for ev in line.events]
+    assert names.count("x.y") == 1
+    assert "x.off" not in names
+    assert obs.hist_stats("x.y.ms", label="kept")["count"] == 1
+
+
 def test_trace_event_cap_drops_and_counts():
     reg = Registry()
-    for i in range(MAX_TRACE_EVENTS + 5):
-        reg.event("t.e", float(i), 1.0)
+    for _ in range(MAX_TRACE_EVENTS + 5):
+        with reg.span("t.e"):
+            pass
     assert len(reg.trace_events()) == MAX_TRACE_EVENTS
     assert reg.snapshot()["dropped_trace_events"] == 5
 

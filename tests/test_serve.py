@@ -269,6 +269,34 @@ def test_front_process_single_dispatch_parity():
         off += x.shape[0]
 
 
+def test_front_queue_wait_one_per_request_within_its_latency():
+    """`serve.queue_ms` (submit to the batch's dispatch) is observed once
+    per request, and never exceeds that request's `serve.request_ms`:
+    within a batch both share one dispatch, so each request's latency
+    less its queue wait is the same non-negative batch time."""
+    svc = _service()
+    rng = np.random.default_rng(11)
+    svc.ingest(*_chunk(rng))
+    front = ServingFront(svc, max_batch=16)
+    for ages_ms in ((50.0,), (30.0, 20.0, 5.0), (1.0, 0.0)):
+        obs.reset()
+        now = time.perf_counter()
+        reqs = [_Request(rng.standard_normal((1, P)).astype(np.float32),
+                         Future(), now - a / 1e3) for a in ages_ms]
+        front._process(reqs)
+        queue = obs.hist_stats("serve.queue_ms")
+        total = obs.hist_stats("serve.request_ms")
+        assert queue["count"] == total["count"] == len(reqs)
+        assert queue["max"] >= max(ages_ms)
+        batch_ms = total["max"] - queue["max"]
+        assert batch_ms > 0             # the dispatch and its wait
+        assert total["min"] - queue["min"] == pytest.approx(batch_ms,
+                                                            abs=1e-6)
+        assert total["sum"] - queue["sum"] == pytest.approx(
+            len(reqs) * batch_ms, abs=1e-6)
+    obs.reset()
+
+
 def test_front_threaded_serving_during_ingest():
     """Threaded smoke: submits race a live ingest/refit loop; every
     result's generation is a real published generation and its scores

@@ -171,6 +171,27 @@ def test_sharded_ingest_matches_host_single_device():
                                atol=1e-5)
 
 
+REFIT_SCOPES = ("refit.power", "refit.lasso", "refit.msolve",
+                "refit.debias", "refit.threshold")
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one_device", "shard_map"])
+def test_refit_phases_are_named_scopes_in_the_compiled_program(sharded):
+    """Every phase of the refit reaches the compiled program's op
+    metadata under its own scope, on the single-device and the
+    shard_map path, so a device profile can be split by phase."""
+    from repro.stream.refit import refit as refit_fn
+    from repro.substrate import data_task_mesh
+    state = jax.eval_shape(lambda: init_stream_state(4, 16))
+    mesh = {"mesh": data_task_mesh(n_task=1, n_data=1)} if sharded else {}
+    text = refit_fn.lower(state, LAM, MU, THR, lasso_iters=8,
+                          debias_iters=8, warm=True, tol=1e-5,
+                          **mesh).compile().as_text()
+    for scope in REFIT_SCOPES:
+        assert re.search(rf'op_name="[^"]*/{re.escape(scope)}/', text), scope
+
+
 _MESH8 = r"""
 import jax, numpy as np
 import jax.numpy as jnp
