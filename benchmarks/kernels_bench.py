@@ -85,7 +85,7 @@ def main():
     X = jax.random.normal(jax.random.PRNGKey(4), (m, p, 1))
     theta = 0.6
     fista_fused = jax.jit(lambda S, z, x, c: fista_step_batched(
-        S, z, x, c, etas, 0.1, theta, interpret=True))
+        S, z, x, jnp.zeros_like(z), c, etas, 0.1, theta, interpret=True))
 
     def _two_op(S, z, x, c):
         xn = ista_step_batched(S, z, c, etas, 0.1, interpret=True)

@@ -51,7 +51,9 @@ from repro import obs
 from repro.core.prox import soft_threshold
 from repro.core.solvers import lasso_stats_step_scale, power_iteration
 from repro.kernels import common as kernel_common
-from repro.kernels.ista_step.ops import fista_step_batched
+from repro.kernels.ista_step.ops import (
+    fista_step_batched, step_routes_to_oracle,
+)
 from repro.kernels.ista_step.ref import (
     fista_step_batched_ref, ista_step_batched_ref,
 )
@@ -65,9 +67,11 @@ def power_iteration_batched(Sigmas: jnp.ndarray, iters: int = 64) -> jnp.ndarray
     return jax.vmap(partial(power_iteration, iters=iters))(Sigmas)
 
 
-def _record_solve(kind: str, n_iters, ceiling: int, out) -> None:
+def _record_solve(kind: str, n_iters, ceiling: int, out, **loop) -> None:
     """Record a solve's iterations-used vs its `iters` ceiling (and the
     early-exit verdict the `tol=`/`return_iters` machinery implies).
+    A lasso-loop solve also passes its `loop` (the keywords of
+    `record_fista_steps`), which counts how its steps moved the carry.
     Eager-only by construction: when a caller jits a public wrapper the
     whole wrapper body runs under trace, the solve's output `out` is a
     tracer and `int(n_iters)` would scalarize one — so this is a no-op
@@ -81,6 +85,44 @@ def _record_solve(kind: str, n_iters, ceiling: int, out) -> None:
     obs.observe("engine.solve.iters_ceiling", ceiling, kind=kind)
     if used < ceiling:
         obs.inc("engine.solve.early_exit", kind=kind)
+    if loop:
+        record_fista_steps(kind, used, ceiling, **loop)
+
+
+def fista_chunk(iters: int, tol, check_every: int) -> int:
+    """Iterations `_fista_loop` runs between two residual checks: the
+    whole budget without a `tol`."""
+    return iters if tol is None else min(check_every, iters)
+
+
+def fista_pairs(n):
+    """The lasso loop's pair schedule for a chunk of n steps (a Python
+    or a traced int): (pairs, unpaired steps). The steps of a pair run
+    in place; an odd chunk's unpaired last step then copies its z' back
+    into z's buffer, once."""
+    return n // 2, n % 2
+
+
+def record_fista_steps(kind: str, n_iters: int, iters: int, tol, *,
+                       p: int, r: int, use_kernel: bool, block=128,
+                       check_every: int = 25) -> None:
+    """Count a lasso-loop solve's steps as `engine.fista_steps{kind,
+    carry}`: `inplace` for the steps of pairs, whose kernel wrote the
+    iterates into the loop's own buffers, `copy` for the unpaired ones,
+    from the loop's chunks (whole ones, then the ceiling's truncated
+    last one). Only the kernel writes in place, so a solve the jnp
+    oracle ran records nothing. Eager only: `_record_solve` calls it,
+    and so does the streaming service after a jitted refit, from the
+    iteration counts on its `RefitInfo`."""
+    if not obs.enabled() or not use_kernel \
+            or step_routes_to_oracle(p, r, block):
+        return
+    chunk = fista_chunk(iters, tol, check_every)
+    whole, rest = divmod(n_iters, max(chunk, 1))
+    copied = whole * fista_pairs(chunk)[1] + fista_pairs(rest)[1]
+    obs.inc("engine.fista_steps", n_iters - copied, kind=kind,
+            carry="inplace")
+    obs.inc("engine.fista_steps", copied, kind=kind, carry="copy")
 
 
 def sufficient_stats(Xs: jnp.ndarray, ys: jnp.ndarray,
@@ -114,17 +156,23 @@ def sufficient_stats(Xs: jnp.ndarray, ys: jnp.ndarray,
                        interpret=interpret, block=block)
 
 
-def _fista_loop(body, init, iters, tol, check_every, residual):
-    """Shared FISTA loop driver. `body` maps a (x, z, t) carry one
-    iteration forward; with `tol=None` it runs the fixed `iters` budget
-    in a fori_loop, otherwise `check_every`-iteration chunks of a
-    while_loop that stops once `residual(x) <= tol`. The final chunk is
-    truncated so `iters` is an EXACT ceiling. Returns (x, n_iters_run)."""
-    if tol is None:
-        carry = jax.lax.fori_loop(0, iters, lambda _, c: body(c), init)
-        return carry[0], jnp.array(iters, jnp.int32)
+def _fista_loop(advance, init, iters, tol, check_every, residual):
+    """The FISTA loop shared by the solvers. `advance(carry, n)` runs n
+    iterations of a carry whose first element is the iterate x; with
+    `tol=None` it runs the fixed `iters` budget as one chunk, otherwise
+    `check_every`-iteration chunks of a while_loop that stops once
+    `residual(x) <= tol`. The final chunk is truncated so `iters` is an
+    EXACT ceiling. Returns (x, n_iters_run).
 
-    K = min(check_every, iters)
+    A chunk is the unit of the lasso's pair schedule (`fista_pairs`):
+    its carry (x, z, w, t) holds a spare stack w beside the momentum
+    point z, because the kernel cannot write z' over z (every row block
+    reads all of z); so a step writes z' into w, and the next step
+    writes z'' back into z's buffer. After each pair every value is in
+    its own buffer and XLA copies nothing."""
+    K = fista_chunk(iters, tol, check_every)
+    if tol is None:
+        return advance(init, K)[0], jnp.array(iters, jnp.int32)
 
     def cond(state):
         _, it, res = state
@@ -133,7 +181,7 @@ def _fista_loop(body, init, iters, tol, check_every, residual):
     def chunk(state):
         carry, it, _ = state
         end = jnp.minimum(it + K, iters)
-        carry = jax.lax.fori_loop(it, end, lambda _, c: body(c), carry)
+        carry = advance(carry, end - it)
         return carry, end, residual(carry[0])
 
     carry, n_iters, _ = jax.lax.while_loop(
@@ -223,19 +271,22 @@ def solve_lasso_batched(Sigmas: jnp.ndarray, cs: jnp.ndarray, lam, *,
     (final chunk truncated to the budget) and stops once the
     prox-gradient KKT residual max|x - soft(x - eta(Sigma x - c),
     eta lam)| drops to `tol`. `return_iters` additionally returns the
-    number of iterations actually run.
+    number of iterations actually run. On the kernel path the steps
+    write their iterates in place (`_solve_lasso_batched`); the counter
+    `engine.fista_steps` says how many did.
     """
-    m = cs.shape[0]
+    m, p = cs.shape[:2]
     r = 1 if cs.ndim == 2 else cs.shape[-1]
     if use_kernel is None:
         use_kernel = kernel_common.kernels_by_default()
-    block = resolve_block_policy(m, cs.shape[1], r, cs.dtype, block,
-                                 use_kernel)
+    block = resolve_block_policy(m, p, r, cs.dtype, block, use_kernel)
     out, n_iters = _solve_lasso_batched(
         Sigmas, cs, lam, etas, beta0, tol, iters=iters,
         use_kernel=use_kernel, interpret=interpret, block=block,
         check_every=check_every)
-    _record_solve("lasso", n_iters, iters, out)
+    _record_solve("lasso", n_iters, iters, out, tol=tol,
+                  check_every=check_every, p=p, r=r,
+                  use_kernel=use_kernel, block=block)
     return (out, n_iters) if return_iters else out
 
 
@@ -243,6 +294,17 @@ def solve_lasso_batched(Sigmas: jnp.ndarray, cs: jnp.ndarray, lam, *,
                                    "block", "check_every"))
 def _solve_lasso_batched(Sigmas, cs, lam, etas, beta0, tol, *, iters,
                          use_kernel, interpret, block, check_every):
+    """The lasso loop. On the kernel path each step writes x' over x
+    and z' over a spare stack w (`fista_step_batched_inplace_pallas`).
+    z' cannot overwrite z: z is the step's contraction operand, and
+    every row block of the step reads all of it. So the carry is
+    (x, z, w, t), and steps run in pairs (`fista_pairs`): the first
+    writes z' into w, the second reads w and writes z'' into z's
+    buffer, which the first has finished reading. After a pair each
+    value is back in its own buffer and the loop's carry is never
+    copied; only an odd chunk's unpaired last step copies its z' back
+    into z's buffer. The iterates are bitwise those of one step per
+    iteration. The jnp oracle ignores w."""
     squeeze = cs.ndim == 2
     C = cs[..., None] if squeeze else cs
     m = C.shape[0]
@@ -251,11 +313,11 @@ def _solve_lasso_batched(Sigmas, cs, lam, etas, beta0, tol, *, iters,
     etas = jnp.broadcast_to(jnp.asarray(etas, C.dtype).reshape(-1), (m,))
 
     if use_kernel:
-        step = lambda Z, X, theta: fista_step_batched(
-            Sigmas, Z, X, C, etas, lam, theta, block=block,
+        step = lambda Z, X, W, theta: fista_step_batched(
+            Sigmas, Z, X, W, C, etas, lam, theta, block=block,
             interpret=interpret)
     else:
-        step = lambda Z, X, theta: fista_step_batched_ref(
+        step = lambda Z, X, W, theta: fista_step_batched_ref(
             Sigmas, Z, X, C, etas, lam, theta)
 
     if beta0 is None:
@@ -265,18 +327,32 @@ def _solve_lasso_batched(Sigmas, cs, lam, etas, beta0, tol, *, iters,
         X0 = jnp.broadcast_to(b0, C.shape).astype(C.dtype)
 
     def body(carry):
-        x, z, t = carry
+        # x' over x, z' into the spare; the old z becomes the spare
+        x, z, w, t = carry
         t_next = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
-        x_next, z_next = step(z, x, (t - 1.0) / t_next)
-        return x_next, z_next, t_next
+        x_next, z_next = step(z, x, w, (t - 1.0) / t_next)
+        return x_next, z_next, z, t_next
+
+    def unpaired_step(carry):
+        # z' back into z's buffer (one copy); the spare's value is moot
+        x, z, _, t = body(carry)
+        return x, z, z, t
+
+    def advance(carry, n):
+        pairs, unpaired = fista_pairs(n)
+        carry = jax.lax.fori_loop(0, pairs, lambda _, c: body(body(c)),
+                                  carry)
+        return jax.lax.fori_loop(0, unpaired,
+                                 lambda _, c: unpaired_step(c), carry)
 
     def residual(x):
         # prox-gradient KKT residual: zero iff x is the lasso optimum
         x_fp = ista_step_batched_ref(Sigmas, x, C, etas, lam)
         return jnp.max(jnp.abs(x_fp - x))
 
-    x, n_iters = _fista_loop(body, (X0, X0, jnp.array(1.0, C.dtype)),
-                             iters, tol, check_every, residual)
+    init = (X0, X0, jnp.zeros_like(X0), jnp.array(1.0, C.dtype))
+    x, n_iters = _fista_loop(advance, init, iters, tol, check_every,
+                             residual)
     return (x[..., 0] if squeeze else x), n_iters
 
 
@@ -308,7 +384,8 @@ def solve_lasso_grid(Sigmas: jnp.ndarray, cs: jnp.ndarray,
     B = _solve_lasso_grid(Sigmas, cs, lams, etas, iters=iters,
                           use_kernel=use_kernel, interpret=interpret,
                           block=block)
-    _record_solve("lasso_grid", iters, iters, B)
+    _record_solve("lasso_grid", iters, iters, B, tol=None, p=p, r=1,
+                  use_kernel=use_kernel, block=block)
     return B
 
 
@@ -367,7 +444,9 @@ def solve_lasso_eq2(Sigmas: jnp.ndarray, cs: jnp.ndarray, lam, *,
     out, n_iters = _solve_lasso_eq2(Sigmas, cs, lam, beta0, lam_max, tol,
                                     iters=iters, use_kernel=use_kernel,
                                     block=block, check_every=check_every)
-    _record_solve("lasso_eq2", n_iters, iters, out)
+    _record_solve("lasso_eq2", n_iters, iters, out, tol=tol,
+                  check_every=check_every, p=p, r=1,
+                  use_kernel=use_kernel, block=block)
     return (out, n_iters) if return_iters else out
 
 
@@ -397,7 +476,8 @@ def solve_lasso_eq2_grid(Sigmas: jnp.ndarray, cs: jnp.ndarray, lams, *,
     block = resolve_block_policy(k * m, p, 1, cs.dtype, None, use_kernel)
     out = _solve_lasso_eq2_grid(Sigmas, cs, lams, iters=iters,
                                 use_kernel=use_kernel, block=block)
-    _record_solve("lasso_eq2_grid", iters, iters, out)
+    _record_solve("lasso_eq2_grid", iters, iters, out, tol=None, p=p,
+                  r=1, use_kernel=use_kernel, block=block)
     return out
 
 
@@ -494,7 +574,7 @@ def _solve_logistic_lasso_batched(Xs, ys, lam, etas, beta0, grad_scale,
     X0 = jnp.zeros((m, p), Xs.dtype) if beta0 is None \
         else beta0.astype(Xs.dtype)
 
-    def body(carry):
+    def body(_, carry):
         x, z, t = carry
         t_next = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
         x_next = prox(z - S * grad(z), S)
@@ -502,10 +582,13 @@ def _solve_logistic_lasso_batched(Xs, ys, lam, etas, beta0, grad_scale,
             if momentum else x_next
         return x_next, z_next, t_next
 
+    def advance(carry, n):
+        return jax.lax.fori_loop(0, n, body, carry)
+
     def residual(x):
         return jnp.max(jnp.abs(prox(x - S * grad(x), S) - x))
 
-    return _fista_loop(body, (X0, X0, jnp.array(1.0, Xs.dtype)),
+    return _fista_loop(advance, (X0, X0, jnp.array(1.0, Xs.dtype)),
                        iters, tol, check_every, residual)
 
 
@@ -554,7 +637,9 @@ def inverse_hessian_batched(Sigmas: jnp.ndarray, mu, iters: int = 600,
     out, n_iters = _inverse_hessian_batched(
         Sigmas, mu, M0, lam_max, tol, iters=iters,
         use_kernel=use_kernel, block=block, check_every=check_every)
-    _record_solve("debias", n_iters, iters, out)
+    _record_solve("debias", n_iters, iters, out, tol=tol,
+                  check_every=check_every, p=p, r=p,
+                  use_kernel=use_kernel, block=block)
     return (out, n_iters) if return_iters else out
 
 

@@ -18,6 +18,9 @@ theta (x_next - x_prev)` from the same VMEM tiles — one kernel dispatch
 and one HBM round trip per FISTA iteration where the two-op path paid a
 kernel plus a separate jnp momentum pass over (m, p, r). The momentum
 coefficient `theta` rides in SMEM next to `etas`/`lam`.
+`fista_step_batched_inplace_pallas` is the same call with its outputs
+aliased onto buffers the engine's loop owns (x_next over x_prev, z_next
+over a spare stack), so the loop's carry is never copied.
 """
 from __future__ import annotations
 
@@ -103,19 +106,18 @@ def _fista_batched_kernel(scal_ref, sig_ref, z_ref, z_tile_ref, x_ref,
         zn_ref[0] = xn + theta.astype(xn.dtype) * (xn - x_ref[0])
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bp", "br", "bk", "interpret"))
-def fista_step_batched_pallas(Sigmas, zs, xs, cs, etas, lam, theta, *,
-                              bp: int = 128, br: int = 128, bk: int = 128,
-                              interpret: bool = False):
-    """One fused FISTA iteration for m tasks: prox step at the momentum
-    point `zs` plus the extrapolation against the previous iterate `xs`.
+def _fista_batched_inplace_kernel(scal_ref, sig_ref, z_ref, z_tile_ref,
+                                  x_ref, c_ref, w_ref, xn_ref, zn_ref,
+                                  acc_ref, *, nk: int, m: int):
+    del w_ref       # z_next's buffer: written through zn_ref, never read
+    _fista_batched_kernel(scal_ref, sig_ref, z_ref, z_tile_ref, x_ref,
+                          c_ref, xn_ref, zn_ref, acc_ref, nk=nk, m=m)
 
-    Sigmas: (m, p, p); zs/xs/cs: (m, p, r); etas: (m,) per-task step
-    sizes; lam scalar or per-task (m,); theta the (traced) scalar
-    momentum coefficient of this iteration. Returns (x_next, z_next),
-    both (m, p, r).
-    """
+
+def _fista_batched_call(Sigmas, zs, xs, cs, etas, lam, theta, ws, *,
+                        bp: int, br: int, bk: int, interpret: bool):
+    """The fused FISTA step's pallas call; with a spare stack `ws`,
+    x_next is written over `xs` and z_next over `ws`."""
     m, p, r = zs.shape
     bp = min(bp, p)
     br = min(br, r)
@@ -130,8 +132,16 @@ def fista_step_batched_pallas(Sigmas, zs, xs, cs, etas, lam, theta, *,
 
     out = jax.ShapeDtypeStruct((m, p, r), zs.dtype)
     tile = pl.BlockSpec((1, bp, br), lambda t, i, j, k: (t, i, j))
+    operands = (scal, Sigmas, zs, zs, xs, cs)
+    kernel, spare, aliases = _fista_batched_kernel, [], {}
+    if ws is not None:
+        # w is never read, so it stays in HBM; x_prev -> x', w -> z'
+        kernel, spare, aliases = (_fista_batched_inplace_kernel,
+                                  [pl.BlockSpec(memory_space=pl.ANY)],
+                                  {4: 0, 6: 1})
+        operands += (ws,)
     return pl.pallas_call(
-        functools.partial(_fista_batched_kernel, nk=nk, m=m),
+        functools.partial(kernel, nk=nk, m=m),
         grid=(m, ni, nj, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # etas ++ lam ++ [theta]
@@ -140,12 +150,55 @@ def fista_step_batched_pallas(Sigmas, zs, xs, cs, etas, lam, theta, *,
             tile,                                   # z (iterate tile)
             tile,                                   # x_prev
             tile,                                   # c
+            *spare,
         ],
         out_specs=(tile, tile),
         out_shape=(out, out),
         scratch_shapes=[pltpu.VMEM((bp, br), jnp.float32)],
+        input_output_aliases=aliases,
         interpret=interpret,
-    )(scal, Sigmas, zs, zs, xs, cs)
+    )(*operands)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("bp", "br", "bk", "interpret"))
+def fista_step_batched_pallas(Sigmas, zs, xs, cs, etas, lam, theta, *,
+                              bp: int = 128, br: int = 128, bk: int = 128,
+                              interpret: bool = False):
+    """One fused FISTA iteration for m tasks: prox step at the momentum
+    point `zs` plus the extrapolation against the previous iterate `xs`.
+
+    Sigmas: (m, p, p); zs/xs/cs: (m, p, r); etas: (m,) per-task step
+    sizes; lam scalar or per-task (m,); theta the (traced) scalar
+    momentum coefficient of this iteration. Returns (x_next, z_next),
+    both (m, p, r), in fresh buffers.
+    """
+    return _fista_batched_call(Sigmas, zs, xs, cs, etas, lam, theta, None,
+                               bp=bp, br=br, bk=bk, interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("bp", "br", "bk", "interpret"))
+def fista_step_batched_inplace_pallas(Sigmas, zs, xs, ws, cs, etas, lam,
+                                      theta, *, bp: int = 128,
+                                      br: int = 128, bk: int = 128,
+                                      interpret: bool = False):
+    """`fista_step_batched_pallas` writing into buffers its caller owns:
+    x_next overwrites `xs` and z_next overwrites the spare stack `ws`
+    (same shape, contents never read). Returns (x_next, z_next).
+
+    x_next may take x_prev's buffer because tile (t, i, j) of `xs` is
+    read only by the grid steps (t, i, j, .), which are the steps that
+    write tile (t, i, j) of x_next. z_next may NOT take `zs`'s buffer:
+    `zs` is the contraction operand, and every row block i reads all of
+    it, so an early row block's z_next tiles would overwrite rows a
+    later row block still has to read. Hence the third stack. A loop
+    that alternates `zs` and `ws` between steps (the engine's pair
+    schedule) keeps every iterate in a buffer it owns, and XLA inserts
+    no loop-carry copy.
+    """
+    return _fista_batched_call(Sigmas, zs, xs, cs, etas, lam, theta, ws,
+                               bp=bp, br=br, bk=bk, interpret=interpret)
 
 
 @functools.partial(jax.jit,

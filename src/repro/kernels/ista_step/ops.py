@@ -18,7 +18,8 @@ from repro.kernels.common import (
 )
 from repro.kernels.common import on_tpu as _on_tpu
 from repro.kernels.ista_step.kernel import (
-    fista_step_batched_pallas, ista_step_batched_pallas, ista_step_pallas,
+    fista_step_batched_inplace_pallas, ista_step_batched_pallas,
+    ista_step_pallas,
 )
 from repro.kernels.ista_step.ref import (
     fista_step_batched_ref, ista_step_batched_ref, ista_step_ref,
@@ -114,20 +115,23 @@ def ista_step_batched(Sigmas, betas, cs, etas, lam, *, block: int = 128,
     return out[..., 0] if squeeze else out
 
 
-def fista_step_batched(Sigmas, zs, xs, cs, etas, lam, theta, *,
+def fista_step_batched(Sigmas, zs, xs, ws, cs, etas, lam, theta, *,
                        block=128, interpret: bool | None = None):
     """One fused FISTA iteration (prox step + momentum extrapolation)
-    for m tasks. Sigmas (m, p, p); zs/xs/cs (m, p) or (m, p, r); etas
-    (m,); lam scalar or per-task (m,); theta the scalar momentum
+    for m tasks. Sigmas (m, p, p); zs/xs/ws/cs (m, p) or (m, p, r);
+    etas (m,); lam scalar or per-task (m,); theta the scalar momentum
     coefficient. Returns (x_next, z_next).
 
-    Same routing policy as `ista_step_batched`: pallas on MXU-friendly
-    shapes (`block` is an int or an autotuned (bp, br, bk) triple),
+    The kernel writes in place (`fista_step_batched_inplace_pallas`):
+    x_next into `xs`'s buffer and z_next into the spare `ws`, whose
+    contents are never read; the oracle ignores `ws`. Same routing
+    policy as `ista_step_batched`: pallas on MXU-friendly shapes
+    (`block` is an int or an autotuned (bp, br, bk) triple),
     batched-jnp oracle on ragged shapes, interpret mode off-TPU.
     """
     squeeze = zs.ndim == 2
     if squeeze:
-        zs, xs, cs = zs[..., None], xs[..., None], cs[..., None]
+        zs, xs, ws, cs = (a[..., None] for a in (zs, xs, ws, cs))
     m, p, r = zs.shape
     bp, br, bk = resolve_blocks(p, r, block)    # validate on every path
     interp = (not _on_tpu()) if interpret is None else interpret
@@ -136,9 +140,9 @@ def fista_step_batched(Sigmas, zs, xs, cs, etas, lam, theta, *,
     if reason is not None:
         xn, zn = fista_step_batched_ref(Sigmas, zs, xs, cs, etas, lam, theta)
     else:
-        xn, zn = fista_step_batched_pallas(Sigmas, zs, xs, cs, etas, lam,
-                                           theta, bp=bp, br=br, bk=bk,
-                                           interpret=interp)
+        xn, zn = fista_step_batched_inplace_pallas(
+            Sigmas, zs, xs, ws, cs, etas, lam, theta, bp=bp, br=br, bk=bk,
+            interpret=interp)
     return (xn[..., 0], zn[..., 0]) if squeeze else (xn, zn)
 
 

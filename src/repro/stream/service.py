@@ -54,6 +54,8 @@ from repro.checkpoint.io import (
     CheckpointError, load_npz, npz_safe_dtype, restore_pytree, save_pytree,
 )
 from repro.checkpoint.manifest import CheckpointStore
+from repro.core.engine import record_fista_steps
+from repro.kernels import common as kernel_common
 from repro.obs import jaxprof
 from repro.stream.accumulate import ingest_sharded
 from repro.stream.guard import IngestGuard, _guarded_fold
@@ -362,9 +364,19 @@ class StreamingDsmlService:
         obs.observe("stream.refit.support_size", float(info.support_size))
         obs.observe("stream.refit.kkt_residual", health.kkt_residual)
         if info.lasso_iters_run is not None:
-            obs.observe("stream.refit.lasso_iters", int(info.lasso_iters_run))
-            obs.observe("stream.refit.debias_iters",
-                        int(info.debias_iters_run))
+            lasso_run = int(info.lasso_iters_run)
+            debias_run = int(info.debias_iters_run)
+            obs.observe("stream.refit.lasso_iters", lasso_run)
+            obs.observe("stream.refit.debias_iters", debias_run)
+            # the solves ran under the refit's jit, where the engine
+            # records nothing; on a mesh these are the slowest shard's
+            use_kernel = kernel_common.kernels_by_default()
+            record_fista_steps("lasso_eq2", lasso_run, l_iters,
+                               self.refit_tol, p=self.p, r=1,
+                               use_kernel=use_kernel)
+            record_fista_steps("debias", debias_run, d_iters,
+                               self.refit_tol, p=self.p, r=self.p,
+                               use_kernel=use_kernel)
         obs.set_gauge("stream.generation", int(info.generation))
         obs.set_gauge("stream.refit.interval_samples", self._interval)
         obs.set_gauge("stream.refit.failures", 0)

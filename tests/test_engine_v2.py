@@ -6,6 +6,9 @@ Contracts (ISSUE 3 / DESIGN.md §10):
     (kernel step + separate jnp momentum) iterates bitwise in
     interpret mode, and the engine's CPU oracle path reproduces the
     historical ref-step loop bitwise;
+  * the in-place pair schedule reproduces the two-output loop's
+    iterates and iteration counts bitwise, and `engine.fista_steps`
+    counts its in-place and copied steps;
   * `tol=` early exit stops before the iteration ceiling and matches
     the full-budget solution to 1e-5;
   * `solve_logistic_lasso_batched` matches the per-task FISTA loops it
@@ -30,6 +33,7 @@ from repro.core import (
 )
 from repro.core.prox import group_soft_threshold, prox_linf, soft_threshold
 from repro.core.solvers import fista, power_iteration
+from repro.kernels.ista_step.kernel import fista_step_batched_pallas
 from repro.kernels.ista_step.ops import ista_step_batched
 from repro.kernels.ista_step.ref import ista_step_batched_ref
 
@@ -145,6 +149,97 @@ def test_fused_momentum_matches_two_op_bitwise_oracle():
     old = _two_op_loop(Sigmas, cs, 0.2, etas, 60)
     new = solve_lasso_batched(Sigmas, cs, 0.2, iters=60, etas=etas)
     np.testing.assert_array_equal(np.asarray(old), np.asarray(new))
+
+
+# ---------------------------------------------------------------------------
+# in-place iterates: bitwise vs the two-output loop they replaced
+# ---------------------------------------------------------------------------
+
+@partial(jax.jit, static_argnames=("iters", "check_every", "block"))
+def _two_output_loop(Sigmas, C, lam, etas, X0, tol, iters, check_every,
+                     block):
+    """The lasso loop before in-place iterates: carry (x, z, t), one
+    fused kernel step per iteration into fresh output buffers."""
+    def body(_, carry):
+        x, z, t = carry
+        t_next = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
+        x_next, z_next = fista_step_batched_pallas(
+            Sigmas, z, x, C, etas, lam, (t - 1.0) / t_next, bp=block[0],
+            br=block[1], bk=block[2], interpret=True)
+        return x_next, z_next, t_next
+
+    def residual(x):
+        x_fp = ista_step_batched_ref(Sigmas, x, C, etas, lam)
+        return jnp.max(jnp.abs(x_fp - x))
+
+    init = (X0, X0, jnp.array(1.0, C.dtype))
+    if tol is None:
+        x = jax.lax.fori_loop(0, iters, body, init)[0]
+        return x, jnp.array(iters, jnp.int32)
+    K = min(check_every, iters)
+
+    def chunk(state):
+        carry, it, _ = state
+        end = jnp.minimum(it + K, iters)
+        carry = jax.lax.fori_loop(it, end, body, carry)
+        return carry, end, residual(carry[0])
+
+    carry, n, _ = jax.lax.while_loop(
+        lambda s: jnp.logical_and(s[1] < iters, s[2] > tol), chunk,
+        (init, jnp.array(0, jnp.int32), jnp.array(jnp.inf, C.dtype)))
+    return carry[0], n
+
+
+def _copied_steps(n_iters, iters, tol, check_every):
+    """Unpaired steps of the in-place loop, from its chunk lengths."""
+    K = iters if tol is None else min(check_every, iters)
+    return sum(min(K, n_iters - s) % 2 for s in range(0, n_iters, K))
+
+
+@pytest.mark.parametrize("r", ["1", "p"])
+@pytest.mark.parametrize("iters,tol,check_every,stops_early", [
+    (40, None, 25, False),       # fixed budget, even
+    (41, None, 25, False),       # fixed budget, odd: one unpaired step
+    (60, 0.0, 10, False),        # check_every divides the ceiling
+    (61, 0.0, 25, False),        # odd chunks, truncated last chunk
+    (301, 1e-4, 25, True),       # odd chunks, tol stops it early
+], ids=["even", "odd", "chunks-even", "chunks-odd", "tol-stops"])
+def test_inplace_iterates_match_two_output_loop_bitwise(
+        r, iters, tol, check_every, stops_early):
+    """The pair schedule (x' over x, z' alternating between z and a
+    spare) gives bitwise the iterates and iteration counts of the
+    two-output loop, for the lasso (r = 1) and the M solve (r = p), and
+    `engine.fista_steps` counts its unpaired steps as copies."""
+    from repro import obs
+    from repro.core.engine import scaled_identity_m0
+    Sigmas, cs = _reg_stats(m=2, p=32)
+    m, p = cs.shape
+    if r == "p":       # the M solve: identity RHS, scaled-identity start
+        cs = jnp.broadcast_to(jnp.eye(p, dtype=cs.dtype), (m, p, p))
+        beta0, lam = scaled_identity_m0(Sigmas), 0.1
+    else:
+        beta0, lam = 0.5 * cs, 0.05
+    C = cs if cs.ndim == 3 else cs[..., None]
+    X0 = beta0 if beta0.ndim == 3 else beta0[..., None]
+    etas = 1.0 / jax.vmap(power_iteration)(Sigmas)
+    block = (8, C.shape[-1], p)
+    old, n_old = _two_output_loop(Sigmas, C, lam, etas, X0, tol, iters,
+                                  check_every, block)
+    obs.reset()
+    new, n_new = solve_lasso_batched(
+        Sigmas, cs, lam, iters=iters, etas=etas, beta0=beta0,
+        use_kernel=True, interpret=True, block=block, tol=tol,
+        check_every=check_every, return_iters=True)
+    n = int(n_new)
+    assert n == int(n_old)
+    assert (n < iters) == stops_early
+    np.testing.assert_array_equal(np.asarray(new),
+                                  np.asarray(old).reshape(new.shape))
+    copied = _copied_steps(n, iters, tol, check_every)
+    count = lambda carry: obs.counter_total(  # noqa: E731
+        "engine.fista_steps", kind="lasso", carry=carry)
+    assert (count("inplace"), count("copy")) == (n - copied, copied)
+    obs.reset()
 
 
 # ---------------------------------------------------------------------------
