@@ -7,7 +7,7 @@ data x task mesh), and `refit` re-runs Algorithm 1 from the state with
 warm starts. `StreamingDsmlService` is the serving driver. DESIGN.md §9.
 """
 from repro.stream.accumulate import (
-    accumulate_stats_fn, accumulate_stats_sharded, ingest_sharded,
+    accumulate_stats_fn, ingest_sharded,
 )
 from repro.stream.guard import IngestGuard, QuarantineRecord
 from repro.stream.health import RefitHealth, refit_health
@@ -24,7 +24,7 @@ from repro.stream.state import (
 )
 
 __all__ = [
-    "accumulate_stats_fn", "accumulate_stats_sharded", "ingest_sharded",
+    "accumulate_stats_fn", "ingest_sharded",
     "IngestGuard", "QuarantineRecord",
     "RefitHealth", "refit_health",
     "RefitInfo", "jaccard_support", "refit", "refit_logistic",

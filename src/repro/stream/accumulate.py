@@ -11,8 +11,7 @@ additivity that makes `StreamState.ingest` exact.
 """
 from __future__ import annotations
 
-from functools import lru_cache
-from typing import Tuple
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -49,28 +48,7 @@ def accumulate_stats_fn(mesh: Mesh, data_axis: str = "data",
     )
 
 
-@lru_cache(maxsize=8)
-def _jitted_accumulator(mesh: Mesh, data_axis: str, task_axis: str):
-    """One compiled accumulator per (mesh, axes) — ingest is the always-
-    on hot path, so per-chunk re-jitting would swamp the psum."""
-    return jax.jit(accumulate_stats_fn(mesh, data_axis, task_axis))
-
-
-def accumulate_stats_sharded(X_batch: jnp.ndarray, y_batch: jnp.ndarray,
-                             mesh: Mesh, data_axis: str = "data",
-                             task_axis: str = "task"
-                             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Chunk-mean sufficient statistics of a device-sharded minibatch.
-
-    Numerically equal (to roundoff) to `engine.sufficient_stats` on the
-    gathered chunk; communicates two psums of partial sums instead.
-    """
-    n = X_batch.shape[1]
-    fn = _jitted_accumulator(mesh, data_axis, task_axis)
-    S_sum, c_sum = fn(X_batch, y_batch)
-    return S_sum / n, c_sum / n
-
-
+@partial(jax.jit, static_argnames=("mesh", "data_axis", "task_axis"))
 def ingest_sharded(state: StreamState, X_batch: jnp.ndarray,
                    y_batch: jnp.ndarray, mesh: Mesh, decay=1.0,
                    data_axis: str = "data",
@@ -78,9 +56,10 @@ def ingest_sharded(state: StreamState, X_batch: jnp.ndarray,
     """`stream.state.ingest` with the row reduction run SPMD over `mesh`.
 
     The state merge itself is elementwise over tasks, so it composes
-    with whatever task sharding the caller keeps the state in.
+    with whatever task sharding the caller keeps the state in; the
+    reduction, the chunk means and the merge are one compiled program.
     """
     n = X_batch.shape[1]
-    Sigma_b, c_b = accumulate_stats_sharded(X_batch, y_batch, mesh,
-                                            data_axis, task_axis)
-    return ingest_stats(state, Sigma_b, c_b, n, decay)
+    S_sum, c_sum = accumulate_stats_fn(mesh, data_axis, task_axis)(
+        X_batch, y_batch)
+    return ingest_stats(state, S_sum / n, c_sum / n, n, decay)

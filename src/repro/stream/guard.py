@@ -32,14 +32,17 @@ unbounded growth) and are counted per reason under
 from __future__ import annotations
 
 from collections import deque
+from functools import lru_cache
 from typing import Deque, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro import obs
 from repro.stream.state import ingest_stats, sufficient_stats
+from repro.substrate import all_gather_tasks, chunk_specs, shard_map
 
 
 class QuarantineRecord(NamedTuple):
@@ -67,6 +70,36 @@ def _chunk_health(X: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
 
 
 _batch_health = jax.jit(_chunk_health)
+
+
+@lru_cache(maxsize=8)
+def mesh_health(mesh, data_axis: str = "data", task_axis: str = "task"):
+    """`_chunk_health` of a chunk laid out on `mesh` by
+    `substrate.feed_chunk`, as one compiled (X, y) -> (3,) program:
+    each device reduces its own block to [sum of x^2, max(|x|, |y|)] and
+    ONE all-gather of those pairs gives every device the whole chunk's
+    health. The chunk never leaves the devices it was fed to."""
+    axes = (data_axis, task_axis)
+
+    def local(X, y):
+        part = jnp.stack([
+            jnp.sum(jnp.square(X.astype(jnp.float32))),
+            jnp.maximum(jnp.max(jnp.abs(X)),
+                        jnp.max(jnp.abs(y))).astype(jnp.float32)])
+        return all_gather_tasks(part[None], axes)
+
+    parts_of = shard_map(local, mesh=mesh,
+                         in_specs=chunk_specs(data_axis, task_axis),
+                         out_specs=P())
+
+    def health(X, y):
+        parts = parts_of(X, y)
+        rms = jnp.sqrt(jnp.sum(parts[:, 0]) / X.size)
+        max_abs = jnp.max(parts[:, 1])
+        return jnp.stack([jnp.isfinite(max_abs).astype(jnp.float32), rms,
+                          max_abs])
+
+    return jax.jit(health)
 
 
 @jax.jit

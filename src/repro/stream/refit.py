@@ -33,7 +33,7 @@ from repro.core.engine import (
 )
 from repro.core.logistic import debias_logistic_batched
 from repro.core.prox import support_from_rows
-from repro.stream.state import StreamState
+from repro.stream.state import StreamState, state_shardings
 from repro.substrate import shard_map
 
 
@@ -42,9 +42,13 @@ class RefitInfo(NamedTuple):
     support_size: jnp.ndarray   # () int32 |S_hat| after thresholding
     generation: jnp.ndarray     # () int32 generation of the NEW state
     # iterations the two solves actually ran (== the ceilings unless a
-    # tol was set); None on paths that never count (e.g. rollback infos)
+    # tol was set); None on paths that never count (e.g. rollback infos).
+    # On a mesh these are the largest over the task shards, and the
+    # shard_* fields hold each shard's count (None on one device)
     lasso_iters_run: jnp.ndarray | None = None
     debias_iters_run: jnp.ndarray | None = None
+    shard_lasso_iters: jnp.ndarray | None = None
+    shard_debias_iters: jnp.ndarray | None = None
 
 
 def jaccard_support(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -77,14 +81,13 @@ def _sharded_solves(mesh, task_axis, Sigmas, cs, lam, mu, beta0, M0, tol,
     """`_solves` per task shard of `mesh`. A cold refit's starts are
     spelled out (zeros; the engine's scaled identity, which is
     symmetric) so every operand has a task axis to shard, and each
-    shard's iteration counts come back as one entry per device (its
-    while loops exit on their own residuals); the largest is
-    reported."""
+    shard's iteration counts come back as one entry per task shard (its
+    while loops exit on their own residuals)."""
     if beta0 is None:
         beta0 = jnp.zeros_like(cs)
     if M0 is None:
         M0 = scaled_identity_m0(Sigmas)
-    T, R, each = P(task_axis), P(), P(tuple(mesh.axis_names))
+    T, R = P(task_axis), P()
     # lam, mu and tol are scalars, replicated; tol=None (fixed budgets)
     # stays a Python None
     scalars = [jnp.asarray(v, cs.dtype) for v in (lam, mu)
@@ -96,8 +99,8 @@ def _sharded_solves(mesh, task_axis, Sigmas, cs, lam, mu, beta0, M0, tol,
 
     beta_hat, Ms, nl, nd = shard_map(
         local, mesh=mesh, in_specs=(T, T, T, T) + (R,) * len(scalars),
-        out_specs=(T, T, each, each))(Sigmas, cs, beta0, M0, *scalars)
-    return beta_hat, Ms, jnp.max(nl), jnp.max(nd)
+        out_specs=(T, T, T, T))(Sigmas, cs, beta0, M0, *scalars)
+    return beta_hat, Ms, nl, nd
 
 
 @partial(jax.jit, static_argnames=("lasso_iters", "debias_iters", "warm",
@@ -126,7 +129,8 @@ def refit(state: StreamState, lam, mu, Lam, lasso_iters: int = 400,
     With a `mesh`, the solves run per shard of its `task_axis` (the
     statistics are task-sharded there, replicated over the data axis);
     everything after them — debias, threshold, drift — is partitioned
-    by XLA.
+    by XLA, and the new state keeps the layout of `state_shardings`.
+    The info then also carries each task shard's iteration counts.
 
     Each phase runs under a `jax.named_scope` — `refit.power`,
     `refit.lasso`, `refit.msolve` (with its warm start), `refit.debias`,
@@ -140,13 +144,15 @@ def refit(state: StreamState, lam, mu, Lam, lasso_iters: int = 400,
             M0 = jnp.where(state.generation > 0, state.Ms,
                            scaled_identity_m0(state.Sigmas))
     iters = dict(lasso_iters=lasso_iters, debias_iters=debias_iters)
+    shard_lasso = shard_debias = None
     if mesh is None:
         beta_hat, Ms, lasso_run, debias_run = _solves(
             state.Sigmas, state.cs, lam, mu, beta0, M0, tol, **iters)
     else:
-        beta_hat, Ms, lasso_run, debias_run = _sharded_solves(
+        beta_hat, Ms, shard_lasso, shard_debias = _sharded_solves(
             mesh, task_axis, state.Sigmas, state.cs, lam, mu, beta0, M0,
             tol, **iters)
+        lasso_run, debias_run = jnp.max(shard_lasso), jnp.max(shard_debias)
     with jax.named_scope("refit.debias"):
         beta_u = debias_batched(state.Sigmas, state.cs, beta_hat, Ms)
     with jax.named_scope("refit.threshold"):
@@ -163,6 +169,13 @@ def refit(state: StreamState, lam, mu, Lam, lasso_iters: int = 400,
             generation=new_state.generation,
             lasso_iters_run=jnp.asarray(lasso_run, jnp.int32),
             debias_iters_run=jnp.asarray(debias_run, jnp.int32))
+    if mesh is not None:
+        # the refreshed state stays where the service keeps it
+        new_state = jax.lax.with_sharding_constraint(
+            new_state, state_shardings(mesh, task_axis))
+        info = info._replace(
+            shard_lasso_iters=shard_lasso.astype(jnp.int32),
+            shard_debias_iters=shard_debias.astype(jnp.int32))
     return new_state, info
 
 

@@ -58,14 +58,14 @@ from repro.core.engine import record_fista_steps
 from repro.kernels import common as kernel_common
 from repro.obs import jaxprof
 from repro.stream.accumulate import ingest_sharded
-from repro.stream.guard import IngestGuard, _guarded_fold
+from repro.stream.guard import IngestGuard, _guarded_fold, mesh_health
 from repro.stream.health import RefitHealth, refit_health
 from repro.stream.refit import RefitInfo, jaccard_support, refit
 from repro.stream.serve import ModelGeneration
 from repro.substrate import feed_chunk
 from repro.stream.state import (
-    StreamState, init_stream_state, init_window, ingest, window_ingest,
-    window_stats,
+    StreamState, init_stream_state, init_window, ingest, state_shardings,
+    window_ingest, window_stats,
 )
 
 # the service's spans also go into any active jax.profiler trace, on
@@ -192,8 +192,20 @@ class StreamingDsmlService:
         # known, for the rank-n ingest and logistic-gradient kernels —
         # before any jitted ingest/refit traces (no-op off-TPU)
         from repro.kernels.autotune import warmup_cache
-        warmup_cache(m, p, chunk_n, dtype=dtype)
-        self.state = init_stream_state(m, p, dtype)
+        if mesh is None:
+            warmup_cache(m, p, chunk_n, dtype=dtype)
+        else:
+            # each device solves its own task shard, at (m / task, p);
+            # the sharded fold is XLA's, so no rank-n kernel to tune
+            n_task = mesh.shape[task_axis]
+            if m % n_task:
+                raise ValueError(f"m={m} tasks do not split over "
+                                 f"{task_axis}={n_task} devices")
+            warmup_cache(m // n_task, p, dtype=dtype)
+        # on a mesh the state is born task-sharded and every later step
+        # (fold, refit, rollback, publish) keeps it so
+        self.state = init_stream_state(m, p, dtype, mesh=mesh,
+                                       task_axis=task_axis)
         self.window = init_window(window, m, p, dtype) if window else None
         self._interval = refit_every
         self._since_refit = 0
@@ -229,18 +241,22 @@ class StreamingDsmlService:
         hands the chunk to the fold (on a TPU it returns before the
         chunk's copy to the device is done), and `stream.ingest.guard`
         the wait for the fold's health probe, which covers the rest of
-        the copy and the fold. On the other paths (no guard, a window,
-        a mesh, an absolute `max_abs` ceiling) it times the
-        asynchronous dispatch only.
+        the copy and the fold. On a mesh it is true latency too, split
+        into `stream.ingest.feed` (the chunk placed on the devices, until
+        every shard is resident), `stream.ingest.probe` (the guard's
+        verdict) and `stream.ingest.fold` (the fold, until it is done).
+        On the other paths (no guard, a window, an absolute `max_abs`
+        ceiling) it times the asynchronous dispatch only.
         """
         # dense host path: probe fused into the fold dispatch (one
-        # launch, one sync — the <2% overhead contract); window/sharded
-        # paths — and a guard with an absolute max_abs ceiling, which
-        # the fused statistics-derived probe cannot evaluate — probe
-        # standalone in front of their folds
+        # launch, one sync — the <2% overhead contract); the window
+        # path — and a guard with an absolute max_abs ceiling, which
+        # the fused statistics-derived probe cannot evaluate — probes
+        # standalone in front of its fold; a mesh probes the chunk
+        # where it was fed, in `_ingest_on_mesh`
         fused = (self.guard is not None and self.window is None
                  and self.mesh is None and self.guard.max_abs is None)
-        if self.guard is not None and not fused:
+        if self.guard is not None and not fused and self.mesh is None:
             ok, _reason = self.guard.admit(X_batch, y_batch)
             if not ok:
                 obs.inc("stream.ingest.quarantined_chunks")
@@ -264,17 +280,8 @@ class StreamingDsmlService:
             elif self.window is not None:
                 self.window = window_ingest(self.window, X_batch, y_batch)
             elif self.mesh is not None:
-                # place the chunk in the accumulator's (task, data)
-                # layout before the fold — per-device transfers through
-                # the substrate feed, no gather, no resharding inside
-                # the compiled worker
-                Xd, yd = feed_chunk(X_batch, y_batch, self.mesh,
-                                    data_axis=self.data_axis,
-                                    task_axis=self.task_axis)
-                self.state = ingest_sharded(self.state, Xd, yd,
-                                            self.mesh, decay=self.decay,
-                                            data_axis=self.data_axis,
-                                            task_axis=self.task_axis)
+                if not self._ingest_on_mesh(X_batch, y_batch):
+                    return None
             else:
                 self.state = ingest(self.state, X_batch, y_batch,
                                     decay=self.decay)
@@ -284,6 +291,29 @@ class StreamingDsmlService:
         if self._since_refit >= self._interval:
             return self.refit()
         return None
+
+    def _ingest_on_mesh(self, X_batch, y_batch) -> bool:
+        """Feed, probe, decide, fold: the chunk goes straight into the
+        accumulator's (task, data) layout — per-device transfers through
+        the substrate feed, never the whole chunk on one device — the
+        guard probes each shard where it landed, and only an admitted
+        chunk is folded. Returns False when the guard quarantined it."""
+        axes = dict(data_axis=self.data_axis, task_axis=self.task_axis)
+        with obs.span("stream.ingest.feed"):
+            Xd, yd = jax.block_until_ready(
+                feed_chunk(X_batch, y_batch, self.mesh, **axes))
+        if self.guard is not None:
+            with obs.span("stream.ingest.probe"):
+                health = np.asarray(mesh_health(self.mesh, **axes)(Xd, yd))
+                ok, _reason = self.guard.record(
+                    health, tuple(int(s) for s in X_batch.shape))
+            if not ok:
+                obs.inc("stream.ingest.quarantined_chunks")
+                return False
+        with obs.span("stream.ingest.fold"):
+            self.state = jax.block_until_ready(ingest_sharded(
+                self.state, Xd, yd, self.mesh, decay=self.decay, **axes))
+        return True
 
     # -- refit policy -----------------------------------------------------
 
@@ -369,14 +399,23 @@ class StreamingDsmlService:
             obs.observe("stream.refit.lasso_iters", lasso_run)
             obs.observe("stream.refit.debias_iters", debias_run)
             # the solves ran under the refit's jit, where the engine
-            # records nothing; on a mesh these are the slowest shard's
+            # records nothing; on a mesh the two above are the slowest
+            # shard's, and each task shard's steps are counted here
+            runs = [(lasso_run, debias_run)]
+            if info.shard_debias_iters is not None:
+                runs = list(zip(np.asarray(info.shard_lasso_iters).tolist(),
+                                np.asarray(info.shard_debias_iters).tolist()))
+                for _, shard_debias in runs:
+                    obs.observe("stream.refit.shard_debias_iters",
+                                shard_debias)
             use_kernel = kernel_common.kernels_by_default()
-            record_fista_steps("lasso_eq2", lasso_run, l_iters,
-                               self.refit_tol, p=self.p, r=1,
-                               use_kernel=use_kernel)
-            record_fista_steps("debias", debias_run, d_iters,
-                               self.refit_tol, p=self.p, r=self.p,
-                               use_kernel=use_kernel)
+            for shard_lasso, shard_debias in runs:
+                record_fista_steps("lasso_eq2", shard_lasso, l_iters,
+                                   self.refit_tol, p=self.p, r=1,
+                                   use_kernel=use_kernel)
+                record_fista_steps("debias", shard_debias, d_iters,
+                                   self.refit_tol, p=self.p, r=self.p,
+                                   use_kernel=use_kernel)
         obs.set_gauge("stream.generation", int(info.generation))
         obs.set_gauge("stream.refit.interval_samples", self._interval)
         obs.set_gauge("stream.refit.failures", 0)
@@ -549,12 +588,20 @@ class StreamingDsmlService:
                 "restore it")
         self._validate_ckpt_compat(data, f"checkpoint '{fname}'")
         restored = restore_pytree(path, self._ckpt_tree())
-        self.state = restored["state"]
+        self.state = self._placed(restored["state"])
         if self.window is not None:
             self.window = restored["window"]
         self._since_refit = 0
         self._refit_failures = 0
         self.publish_model()
+
+    def _placed(self, state: StreamState) -> StreamState:
+        """A restored state in the service's layout: task-sharded again
+        on a mesh."""
+        if self.mesh is None:
+            return state
+        return jax.device_put(state, state_shardings(self.mesh,
+                                                     self.task_axis))
 
     def checkpoint(self) -> Optional[str]:
         """Persist the current generation to the crash-safe store
@@ -571,7 +618,7 @@ class StreamingDsmlService:
         if self.ckpt_store is None:
             raise ValueError("no ckpt_dir configured on this service")
         tree, generation = self.ckpt_store.load(self._ckpt_tree())
-        self.state = tree["state"]
+        self.state = self._placed(tree["state"])
         if self.window is not None:
             self.window = tree["window"]
         self._since_refit = 0

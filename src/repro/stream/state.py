@@ -23,10 +23,12 @@ All functions are pure and jit-safe; `StreamState` round-trips through
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.engine import sufficient_stats
 
@@ -43,8 +45,25 @@ class StreamState(NamedTuple):
     generation: jnp.ndarray  # ()   int32 refit generation
 
 
-def init_stream_state(m: int, p: int, dtype=jnp.float32) -> StreamState:
-    """Empty state for m tasks in p dimensions (zero samples seen)."""
+def state_shardings(mesh, task_axis: str = "task") -> StreamState:
+    """Where a task-sharded state lives on `mesh`: every per-task field
+    split over `task_axis`, the shared support and the generation
+    replicated (and everything replicated over the other axes)."""
+    T, R = NamedSharding(mesh, P(task_axis)), NamedSharding(mesh, P())
+    return StreamState(Sigmas=T, cs=T, counts=T, beta_local=T, Ms=T,
+                       beta_u=T, beta_tilde=T, support=R, generation=R)
+
+
+def init_stream_state(m: int, p: int, dtype=jnp.float32, *, mesh=None,
+                      task_axis: str = "task") -> StreamState:
+    """Empty state for m tasks in p dimensions (zero samples seen).
+
+    With a `mesh` the state is born task-sharded (`state_shardings`):
+    each device builds only its own tasks' stacks, so no device ever
+    holds more than its share of the (m, p, p) stacks."""
+    if mesh is not None:
+        return jax.jit(partial(init_stream_state, m, p, dtype),
+                       out_shardings=state_shardings(mesh, task_axis))()
     return StreamState(
         Sigmas=jnp.zeros((m, p, p), dtype),
         cs=jnp.zeros((m, p), dtype),

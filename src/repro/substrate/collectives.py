@@ -24,21 +24,23 @@ import jax.numpy as jnp
 from repro import obs
 
 
-def _record(op: str, x, axis: str) -> None:
+def _record(op: str, x, axis) -> None:
     if not obs.enabled():
         return
+    label = axis if isinstance(axis, str) else "+".join(axis)
     try:
         k = int(jax.lax.psum(1, axis))
     except Exception:
         k = 0       # axis not bound (helper called outside shard_map)
-        obs.inc("collective.axis_unbound", op=op, axis=axis)
+        obs.inc("collective.axis_unbound", op=op, axis=label)
     nbytes = int(x.size) * x.dtype.itemsize
-    obs.inc("collective.calls", op=op, axis=axis)
-    obs.inc("collective.bytes", k * nbytes, op=op, axis=axis)
+    obs.inc("collective.calls", op=op, axis=label)
+    obs.inc("collective.bytes", k * nbytes, op=op, axis=label)
 
 
-def all_gather_tasks(x: jnp.ndarray, axis: str) -> jnp.ndarray:
-    """Gather shards along mesh `axis`, concatenated on dim 0 (tiled)."""
+def all_gather_tasks(x: jnp.ndarray, axis) -> jnp.ndarray:
+    """Gather shards along mesh `axis` (a name, or a tuple of names for
+    every device of those axes), concatenated on dim 0 (tiled)."""
     _record("all_gather_tasks", x, axis)
     return jax.lax.all_gather(x, axis, tiled=True)
 

@@ -197,3 +197,92 @@ def test_sharded_warm_refit_compiles_in_place(topo, chip_paths):
                           NamedSharding(mesh, PartitionSpec("task")), mesh)
     assert "tpu_custom_call" in hlo
     _assert_msolve_in_place(hlo, m // 4, p)
+
+
+# the four-chip many-tenant deployment: 1,024 tasks task-sharded over a
+# data=1 x task=4 mesh, 256 tasks per chip, 512-row chunks
+T4_M, T4_N, T4_P = 1024, 512, 1024
+CHIP_BYTES = 16e9
+COLLECTIVE = re.compile(r"= (\S+) (?:all-gather|all-reduce|all-to-all|"
+                        r"collective-permute|reduce-scatter)[-a-z]*\(")
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices).reshape(1, 4), ("data", "task"),
+                axis_types=(AxisType.Auto,) * 2)
+
+
+def _t4_state(mesh):
+    from repro.stream.state import StreamState, state_shardings
+    at = state_shardings(mesh)
+    m, p = T4_M, T4_P
+    return StreamState(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=getattr(at, f))
+        for f, shape, dtype in zip(StreamState._fields, [
+            (m, p, p), (m, p), (m,), (m, p), (m, p, p), (m, p), (m, p),
+            (p,), ()], [jnp.float32] * 7 + [bool, jnp.int32])])
+
+
+def _t4_chunk(mesh):
+    from repro.substrate import chunk_specs
+    sx, sy = chunk_specs()
+    return (jax.ShapeDtypeStruct((T4_M, T4_N, T4_P), jnp.float32,
+                                 sharding=NamedSharding(mesh, sx)),
+            jax.ShapeDtypeStruct((T4_M, T4_N), jnp.float32,
+                                 sharding=NamedSharding(mesh, sy)))
+
+
+def _per_chip(compiled, what) -> str:
+    """The program's bytes on each chip under 16 GB; no collective moves
+    more than p values (never an (m, p, p) or (m, p) stack). Returns
+    the HLO text."""
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < CHIP_BYTES, f"{what}: {total} bytes on one chip"
+    hlo = compiled.as_text()
+    for typ in COLLECTIVE.findall(hlo):
+        dims = [int(d) for d in re.findall(r"\d+", typ.split("{")[0])[1:]]
+        assert int(np.prod(dims)) <= T4_P, f"{what}: collective {typ}"
+    return hlo
+
+
+@pytest.mark.parametrize("warm", [False, True], ids=["cold", "warm"])
+def test_tenants4_sharded_refit_fits_each_chip(mesh4, chip_paths, warm):
+    """The 1,024-task refit on four chips: each chip holds its 256
+    tasks' stacks, its M solve runs the kernel in place on a (256, 1024,
+    1024) shard, and only the threshold's (p,) reduction crosses chips."""
+    from repro.stream.refit import refit
+    iters = (100, 150) if warm else (400, 600)
+    compiled = refit.lower(
+        _t4_state(mesh4), 0.33, 0.08, 6.4, lasso_iters=iters[0],
+        debias_iters=iters[1], warm=warm, tol=1e-5, mesh=mesh4).compile()
+    hlo = _per_chip(compiled, "tenants4 refit")
+    _assert_msolve_in_place(hlo, T4_M // 4, T4_P)
+
+
+def test_tenants4_mesh_ingest_fits_each_chip(mesh4):
+    """The state born on the mesh (each chip builds only its 256 tasks'
+    stacks), the mesh fold, the guard's probe of the fed chunk and the
+    refit's health check over the sharded candidate: nothing gathered,
+    each within one chip."""
+    from functools import partial
+
+    from repro.stream.accumulate import ingest_sharded
+    from repro.stream.guard import mesh_health
+    from repro.stream.health import _model_health
+    from repro.stream.state import init_stream_state, state_shardings
+    born = jax.jit(partial(init_stream_state, T4_M, T4_P),
+                   out_shardings=state_shardings(mesh4)).lower().compile()
+    _per_chip(born, "tenants4 state")
+    # two (256, 1024, 1024) float32 stacks per chip, and small change
+    assert born.memory_analysis().output_size_in_bytes < 2.2e9
+    state = _t4_state(mesh4)
+    X, y = _t4_chunk(mesh4)
+    _per_chip(ingest_sharded.lower(state, X, y, mesh4, 1.0).compile(),
+              "tenants4 fold")
+    _per_chip(mesh_health(mesh4).lower(X, y).compile(), "tenants4 probe")
+    _per_chip(_model_health.lower(
+        state.Sigmas, state.cs, state.beta_local, state.Ms, state.beta_u,
+        state.beta_tilde, 0.33).compile(), "tenants4 health")
