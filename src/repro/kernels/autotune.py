@@ -33,6 +33,7 @@ a miss; an explicit `block=` always wins and never touches the cache.
 """
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import re
@@ -44,7 +45,9 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.kernels.common import LANE, lane_fit_block, on_tpu
+from repro.kernels.common import (
+    LANE, aligned_fit_block, lane_fit_block, on_tpu,
+)
 from repro.kernels.ista_step.kernel import fista_step_batched_pallas
 from repro.kernels.ista_step.ops import (
     STEP_VMEM_BUDGET, resolve_blocks, step_vmem_bytes,
@@ -65,6 +68,12 @@ CACHE_FILE = "autotune.json"
 # compiler accepts
 LANE_CANDIDATES = (128, 256, 512)
 SUBLANE_CANDIDATES = (32, 64, 128, 256)
+# the many-right-hand-side step's output tile (bp, br) and its
+# streamed contraction tile bk; at most MAX_CANDIDATES are swept
+WIDE_BP = (256, 512, 1024)
+WIDE_BR = (512, 1024, 2048)
+WIDE_BK = (128, 256, 512)
+MAX_CANDIDATES = 7
 
 _memory_cache: Dict[str, tuple] = {}
 
@@ -75,13 +84,20 @@ def cache_path() -> Path:
 
 
 def cache_key(kernel: str, backend: str, dims: Dict[str, int],
-              dtype) -> str:
+              dtype, space=None) -> str:
     """Per-kernel-namespaced key: "<kernel>/<backend>_m4_p128_..._f32".
     Entries for different kernels can never collide even when their
     dimension tuples coincide (e.g. a (m, n, p) logistic sweep vs a
-    (m, p, r) solver sweep with equal numbers)."""
+    (m, p, r) solver sweep with equal numbers). With `space`, the
+    candidate list the sweep times, the key ends in "@<digest>" of that
+    list: a winner of another list is never served, so a change to the
+    candidates re-tunes by itself."""
     dim_s = "_".join(f"{k}{v}" for k, v in dims.items())
-    return f"{kernel}/{backend}_{dim_s}_{jnp.dtype(dtype).name}"
+    key = f"{kernel}/{backend}_{dim_s}_{jnp.dtype(dtype).name}"
+    if space is None:
+        return key
+    digest = hashlib.sha1(json.dumps([list(c) for c in space]).encode())
+    return f"{key}@{digest.hexdigest()[:8]}"
 
 
 def clear_memory_cache() -> None:
@@ -145,15 +161,30 @@ def _divisor_candidates(size: int, cands=SUBLANE_CANDIDATES) -> List[int]:
 
 
 def block_candidates(p: int, r: int) -> List[Tuple[int, int, int]]:
-    """Legal (bp, br, bk) tilings to sweep for a (p, r) solve. bk is
-    tied to bp (the contraction tile streams the same Sigma rows the
-    output tile covers) and lands on lanes, as br does, so both take
-    lane candidates; the sweep is |bp| x |br| candidates inside the
-    step kernel's VMEM budget."""
-    bps = _divisor_candidates(p, LANE_CANDIDATES)
-    brs = [1] if r == 1 else _divisor_candidates(r, LANE_CANDIDATES)
-    return [(bp, br, bp) for bp in bps for br in brs
-            if step_vmem_bytes(bp, br, bp) <= STEP_VMEM_BUDGET]
+    """Legal (bp, br, bk) tilings to sweep for a (p, r) solve.
+
+    One right-hand side (the lasso) reads Sigma once whatever its tile:
+    bk is tied to bp and both take lane candidates. Many right-hand
+    sides (r >= 128, the M solve) read Sigma r / br times and z p / bp
+    times per step, so the output tile grows as wide as the VMEM budget
+    allows over a streamed contraction tile of 128 to 512; of those,
+    the `MAX_CANDIDATES` with the fewest such passes (then the least
+    VMEM) are swept."""
+    if r < LANE:
+        bps = _divisor_candidates(p, LANE_CANDIDATES)
+        brs = [1] if r == 1 else _divisor_candidates(r, LANE_CANDIDATES)
+        return [(bp, br, bp) for bp in bps for br in brs
+                if step_vmem_bytes(bp, br, bp) <= STEP_VMEM_BUDGET]
+    bps = [b for b in WIDE_BP if p % b == 0] or [
+        aligned_fit_block(p, WIDE_BP[-1])]
+    brs = [b for b in WIDE_BR if r % b == 0] or [
+        lane_fit_block(r, WIDE_BR[-1])]
+    bks = [b for b in WIDE_BK if p % b == 0] or [
+        lane_fit_block(p, WIDE_BK[-1])]
+    cands = [(bp, br, bk) for bp in bps for br in brs for bk in bks
+             if step_vmem_bytes(bp, br, bk) <= STEP_VMEM_BUDGET]
+    cands.sort(key=lambda c: (p // c[0] + r // c[1], step_vmem_bytes(*c)))
+    return cands[:MAX_CANDIDATES]
 
 
 def logistic_candidates(n: int, p: int) -> List[Tuple[int, int]]:
@@ -202,13 +233,14 @@ def _time_candidate(fn, reps: int) -> float:
 def _autotune(kernel: str, dims: Dict[str, int], default, candidates,
               make_sweep: Callable, *, dtype, backend: str | None,
               interpret: bool | None, reps: int, use_disk: bool,
-              sweep: bool):
+              sweep: bool, space=None):
     """Shared cache-then-sweep policy behind every `autotune_*` entry
     point. `make_sweep(interp)` builds the synthetic sweep inputs and
     returns a `candidate -> timing thunk` factory — called only when a
     sweep actually runs, so warm-cache hits on the engine hot path
     never pay a problem-sized allocation. The winner is written back to
-    both caches.
+    both caches, under a key that names the candidate list where `space`
+    is that list (`cache_key`).
 
     With `sweep=False` (the engine's block policies, which run under a
     caller's jit trace where a timed candidate would return a tracer) a
@@ -225,7 +257,7 @@ def _autotune(kernel: str, dims: Dict[str, int], default, candidates,
         obs.inc("autotune.cache", kernel=kernel, event="default_multiprocess")
         return default
     backend = jax.default_backend() if backend is None else backend
-    key = cache_key(kernel, backend, dims, dtype)
+    key = cache_key(kernel, backend, dims, dtype, space)
     if key in _memory_cache:
         obs.inc("autotune.cache", kernel=kernel, event="hit_memory")
         return _memory_cache[key]
@@ -293,7 +325,8 @@ def autotune_block(m: int, p: int, r: int, *, dtype=jnp.float32,
                    reps: int = 2, use_disk: bool = True, sweep: bool = True
                    ) -> Tuple[int, int, int]:
     """Winning (bp, br, bk) tiling for a batched FISTA solve step of
-    this (m, p, r) shape (kernel namespace `fista_step`)."""
+    this (m, p, r) shape (kernel namespace `fista_step`, keyed by the
+    candidate list as well as the shape)."""
     def make_sweep(interp):
         k0, k1, k2 = jax.random.split(jax.random.PRNGKey(0), 3)
         Sigmas = jax.random.normal(k0, (m, p, p), dtype)
@@ -304,12 +337,12 @@ def autotune_block(m: int, p: int, r: int, *, dtype=jnp.float32,
             Sigmas, zs, zs, cs, etas, 0.1, 0.5, bp=cand[0], br=cand[1],
             bk=cand[2], interpret=interp)
 
+    cands = block_candidates(p, r) if candidates is None else candidates
     return _autotune(
         "fista_step", {"m": m, "p": p, "r": r},
-        resolve_blocks(p, r, 128),
-        block_candidates(p, r) if candidates is None else candidates,
-        make_sweep, dtype=dtype, backend=backend, interpret=interpret,
-        reps=reps, use_disk=use_disk, sweep=sweep)
+        resolve_blocks(p, r, 128), cands, make_sweep, dtype=dtype,
+        backend=backend, interpret=interpret, reps=reps,
+        use_disk=use_disk, sweep=sweep, space=cands)
 
 
 def autotune_logistic_block(m: int, n: int, p: int, *, dtype=jnp.float32,

@@ -132,7 +132,8 @@ def is_ragged_samples(n: int, p: int) -> bool:
     return bool(n % 8 or p % 8)
 
 
-def record_route(kernel: str, reason: str | None, *, blocks=None) -> None:
+def record_route(kernel: str, reason: str | None, *, blocks=None,
+                 vmem_mib=None) -> None:
     """THE telemetry funnel for dispatcher routing decisions — the one
     audited exception to lint code RL108 (no `repro.obs` calls in
     jit-reachable code). Dispatchers run at trace time under jit, so
@@ -142,11 +143,13 @@ def record_route(kernel: str, reason: str | None, *, blocks=None) -> None:
 
     `reason` is None on the kernel path, else why the oracle won
     (`ragged` / `sliver` / `vmem_budget` / `backend`); `blocks` is the
-    resolved tile tuple."""
+    resolved tile tuple; `vmem_mib`, where given, the scoped VMEM limit
+    the kernel call asks for (`default` for none)."""
     if not obs.enabled():
         return
+    extra = {} if vmem_mib is None else {"vmem_mib": str(vmem_mib)}
     obs.inc("dispatch.route", kernel=kernel,
             outcome="kernel" if reason is None else "oracle",
             reason=reason or "kernel",
             blocks="none" if blocks is None
-            else "x".join(str(b) for b in blocks))
+            else "x".join(str(b) for b in blocks), **extra)

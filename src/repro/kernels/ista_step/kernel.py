@@ -21,6 +21,11 @@ coefficient `theta` rides in SMEM next to `etas`/`lam`.
 `fista_step_batched_inplace_pallas` is the same call with its outputs
 aliased onto buffers the engine's loop owns (x_next over x_prev, z_next
 over a spare stack), so the loop's carry is never copied.
+
+Each call asks Mosaic for the scoped VMEM its tiles take
+(`step_vmem_limit`) where that is more than the default limit, so the
+r = p step can take output tiles wide enough that Sigma and z are read
+from HBM once or twice per step instead of p / br and p / bp times.
 """
 from __future__ import annotations
 
@@ -30,6 +35,42 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import LANE
+
+MIB = 1024 * 1024
+# Mosaic's scoped VMEM limit for a call that asks for none
+DEFAULT_SCOPED_VMEM = 16 * MIB
+# asked for beyond the byte model, so a call never sits at its edge
+VMEM_HEADROOM = 4 * MIB
+
+
+def step_vmem_bytes(bp: int, br: int, bk: int) -> int:
+    """VMEM one grid step of the step kernels allocates, at f32 with
+    lanes padded to full 128-lane register tiles: the (bp, bk) Sigma
+    tile and the (bk, br) contraction tile, double-buffered, and
+    fourteen (bp, br) tiles: the five iterate/x_prev/c/output windows
+    double-buffered, the accumulator, and three epilogue temporaries.
+    Exact for the fused FISTA step: compiles for a described v5e
+    report this scoped allocation at every tiling tried, from (256,
+    512, 256) to (1024, 2048, 256); the ISTA steps hold fewer
+    windows."""
+    pad = lambda d: -(-d // LANE) * LANE
+    return 8 * bp * pad(bk) + 8 * bk * pad(br) + 56 * bp * pad(br)
+
+
+def step_vmem_limit(bp: int, br: int, bk: int) -> int | None:
+    """The scoped VMEM limit a step call with these tiles asks for, in
+    bytes (whole MiB): its byte model plus `VMEM_HEADROOM`, or None
+    where the default limit already holds that."""
+    need = -(-(step_vmem_bytes(bp, br, bk) + VMEM_HEADROOM) // MIB) * MIB
+    return need if need > DEFAULT_SCOPED_VMEM else None
+
+
+def _compiler_params(bp: int, br: int, bk: int):
+    limit = step_vmem_limit(bp, br, bk)
+    return None if limit is None else pltpu.CompilerParams(
+        vmem_limit_bytes=limit)
 
 
 def _ista_kernel(eta_lam_ref, sig_ref, beta_ref, beta_tile_ref, c_ref,
@@ -156,6 +197,7 @@ def _fista_batched_call(Sigmas, zs, xs, cs, etas, lam, theta, ws, *,
         out_shape=(out, out),
         scratch_shapes=[pltpu.VMEM((bp, br), jnp.float32)],
         input_output_aliases=aliases,
+        compiler_params=_compiler_params(bp, br, bk),
         interpret=interpret,
     )(*operands)
 
@@ -240,6 +282,7 @@ def ista_step_batched_pallas(Sigmas, betas, cs, etas, lam, *, bp: int = 128,
         out_specs=pl.BlockSpec((1, bp, br), lambda t, i, j, k: (t, i, j)),
         out_shape=jax.ShapeDtypeStruct((m, p, r), betas.dtype),
         scratch_shapes=[pltpu.VMEM((bp, br), jnp.float32)],
+        compiler_params=_compiler_params(bp, br, bk),
         interpret=interpret,
     )(eta_lam, Sigmas, betas, betas, cs)
 
@@ -272,5 +315,6 @@ def ista_step_pallas(Sigma, beta, c, eta, lam, *, bp: int = 128,
         out_specs=pl.BlockSpec((bp, br), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((p, r), beta.dtype),
         scratch_shapes=[pltpu.VMEM((bp, br), jnp.float32)],
+        compiler_params=_compiler_params(bp, br, bk),
         interpret=interpret,
     )(eta_lam, Sigma, beta, beta, c)

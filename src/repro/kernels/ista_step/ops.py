@@ -14,12 +14,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.common import (
-    LANE, aligned_fit_block, lane_fit_block, record_route, validate_block,
+    aligned_fit_block, lane_fit_block, record_route, validate_block,
 )
 from repro.kernels.common import on_tpu as _on_tpu
 from repro.kernels.ista_step.kernel import (
-    fista_step_batched_inplace_pallas, ista_step_batched_pallas,
-    ista_step_pallas,
+    MIB, fista_step_batched_inplace_pallas, ista_step_batched_pallas,
+    ista_step_pallas, step_vmem_bytes, step_vmem_limit,
 )
 from repro.kernels.ista_step.ref import (
     fista_step_batched_ref, ista_step_batched_ref, ista_step_ref,
@@ -34,19 +34,9 @@ def is_ragged(p: int, r: int) -> bool:
     return bool(p % 8 or (r % 8 and r != 1))
 
 
-# per-dispatch VMEM budget for one grid step, the same 8 MB envelope
-# as the sample-streaming kernels
-STEP_VMEM_BUDGET = 8 * 1024 * 1024
-
-
-def step_vmem_bytes(bp: int, br: int, bk: int) -> int:
-    """Estimated VMEM footprint of one grid step of the batched step
-    kernels: the (bp, bk) Sigma tile and the (bk, br) contraction tile,
-    the five (bp, br) iterate/c/output tiles, all double-buffered at
-    f32 with lanes padded to full 128-lane register tiles, plus the f32
-    (bp, br) accumulator."""
-    pad = lambda d: -(-d // LANE) * LANE
-    return 8 * bp * pad(bk) + 8 * bk * pad(br) + 44 * bp * pad(br)
+# the most VMEM one grid step may take by `step_vmem_bytes`: a v5e core
+# holds 128 MiB, and the call asks for this plus `VMEM_HEADROOM`
+STEP_VMEM_BUDGET = 96 * MIB
 
 
 def resolve_blocks(p: int, r: int, block) -> tuple:
@@ -136,7 +126,10 @@ def fista_step_batched(Sigmas, zs, xs, ws, cs, etas, lam, theta, *,
     bp, br, bk = resolve_blocks(p, r, block)    # validate on every path
     interp = (not _on_tpu()) if interpret is None else interpret
     reason = step_route_reason(p, r, block)
-    record_route("fista_step_batched", reason, blocks=(bp, br, bk))
+    limit = step_vmem_limit(bp, br, bk)
+    record_route("fista_step_batched", reason, blocks=(bp, br, bk),
+                 vmem_mib=None if reason is not None
+                 else "default" if limit is None else limit // MIB)
     if reason is not None:
         xn, zn = fista_step_batched_ref(Sigmas, zs, xs, cs, etas, lam, theta)
     else:

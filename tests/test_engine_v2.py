@@ -196,23 +196,43 @@ def _copied_steps(n_iters, iters, tol, check_every):
     return sum(min(K, n_iters - s) % 2 for s in range(0, n_iters, K))
 
 
+INPLACE_CASES = pytest.mark.parametrize(
+    "iters,tol,check_every,stops_early", [
+        (40, None, 25, False),       # fixed budget, even
+        (41, None, 25, False),       # fixed budget, odd: one unpaired step
+        (60, 0.0, 10, False),        # check_every divides the ceiling
+        (61, 0.0, 25, False),        # odd chunks, truncated last chunk
+        (301, 1e-4, 25, True),       # odd chunks, tol stops it early
+    ], ids=["even", "odd", "chunks-even", "chunks-odd", "tol-stops"])
+
+
 @pytest.mark.parametrize("r", ["1", "p"])
-@pytest.mark.parametrize("iters,tol,check_every,stops_early", [
-    (40, None, 25, False),       # fixed budget, even
-    (41, None, 25, False),       # fixed budget, odd: one unpaired step
-    (60, 0.0, 10, False),        # check_every divides the ceiling
-    (61, 0.0, 25, False),        # odd chunks, truncated last chunk
-    (301, 1e-4, 25, True),       # odd chunks, tol stops it early
-], ids=["even", "odd", "chunks-even", "chunks-odd", "tol-stops"])
+@INPLACE_CASES
 def test_inplace_iterates_match_two_output_loop_bitwise(
         r, iters, tol, check_every, stops_early):
     """The pair schedule (x' over x, z' alternating between z and a
     spare) gives bitwise the iterates and iteration counts of the
     two-output loop, for the lasso (r = 1) and the M solve (r = p), and
     `engine.fista_steps` counts its unpaired steps as copies."""
+    _check_inplace_bitwise(r, iters, tol, check_every, stops_early,
+                           p=32, block=lambda p, r: (8, r, p))
+
+
+@pytest.mark.parametrize("r", ["1", "p"])
+@INPLACE_CASES
+def test_inplace_iterates_bitwise_at_decoupled_bk(
+        r, iters, tol, check_every, stops_early):
+    """The same, with the M solve's wide tiles: the whole output rows
+    in one tile and the contraction streamed in 128-wide parts."""
+    _check_inplace_bitwise(r, iters, tol, check_every, stops_early,
+                           p=256, block=lambda p, r: (p, r, 128))
+
+
+def _check_inplace_bitwise(r, iters, tol, check_every, stops_early, *, p,
+                           block):
     from repro import obs
     from repro.core.engine import scaled_identity_m0
-    Sigmas, cs = _reg_stats(m=2, p=32)
+    Sigmas, cs = _reg_stats(m=2, p=p)
     m, p = cs.shape
     if r == "p":       # the M solve: identity RHS, scaled-identity start
         cs = jnp.broadcast_to(jnp.eye(p, dtype=cs.dtype), (m, p, p))
@@ -222,7 +242,7 @@ def test_inplace_iterates_match_two_output_loop_bitwise(
     C = cs if cs.ndim == 3 else cs[..., None]
     X0 = beta0 if beta0.ndim == 3 else beta0[..., None]
     etas = 1.0 / jax.vmap(power_iteration)(Sigmas)
-    block = (8, C.shape[-1], p)
+    block = block(p, C.shape[-1])
     old, n_old = _two_output_loop(Sigmas, C, lam, etas, X0, tol, iters,
                                   check_every, block)
     obs.reset()
@@ -447,9 +467,10 @@ def test_autotune_keys_namespaced_per_kernel(tmp_path, monkeypatch):
 
 
 def test_autotune_migrates_legacy_unnamespaced_cache(tmp_path, monkeypatch):
-    """Pre-namespace autotune.json files (fista-only, bare keys) keep
-    serving: loads migrate them under fista_step/ and rewrite the file
-    — and the migrated entry is served without re-timing."""
+    """Pre-namespace autotune.json files (fista-only, bare keys) stay
+    readable: loads migrate them under fista_step/ and rewrite the file.
+    The migrated entry names no candidate list, so it is not served:
+    the shape is tuned anew under its list's key, beside the old one."""
     import json
     from repro.kernels import autotune
     monkeypatch.setenv("REPRO_CACHE_DIR", str(tmp_path))
@@ -457,13 +478,18 @@ def test_autotune_migrates_legacy_unnamespaced_cache(tmp_path, monkeypatch):
     autotune.cache_path().parent.mkdir(parents=True, exist_ok=True)
     autotune.cache_path().write_text(
         json.dumps({"cpu_m2_p32_r1_float32": [32, 1, 32]}))
-    monkeypatch.setattr(
-        autotune, "_time_candidate",
-        lambda fn, reps: (_ for _ in ()).throw(
-            AssertionError("migrated key must be served, not re-timed")))
-    assert autotune.autotune_block(2, 32, 1, reps=1) == (32, 1, 32)
+    timed = []
+    monkeypatch.setattr(autotune, "_time_candidate",
+                        lambda fn, reps: timed.append(1) or 1.0)
+    cands = autotune.block_candidates(32, 1)
+    blk = autotune.autotune_block(2, 32, 1, reps=1)
+    assert blk in cands and len(timed) == len(cands)
+    tuned = autotune.cache_key("fista_step", "cpu",
+                               {"m": 2, "p": 32, "r": 1}, jnp.float32,
+                               cands)
     disk = json.loads(autotune.cache_path().read_text())
-    assert disk == {"fista_step/cpu_m2_p32_r1_float32": [32, 1, 32]}
+    assert disk == {"fista_step/cpu_m2_p32_r1_float32": [32, 1, 32],
+                    tuned: list(blk)}
 
 
 def test_autotune_migrates_legacy_logistic_int_values(tmp_path, monkeypatch):

@@ -6,7 +6,8 @@ tiling the chip's compiler refuses (a block that breaks the (8, 128)
 rule, a kernel over its VMEM) fails here instead of on the chip. Shapes
 are the chip smoke's (m=128 tasks, p=1024 features, n=512-row chunks),
 the paper's p=200 through the dispatcher's resolved blocks, and every
-candidate `autotune.warmup_cache` would sweep at the smoke's shapes.
+candidate `autotune.warmup_cache` would sweep at the smoke's shapes, and
+the widest M-solve tiling at each deployment's per-chip shape.
 The warm refit is compiled at the benchmark's two deployments, on one
 chip and sharded over four, and its M solve is checked to run in place.
 
@@ -26,7 +27,9 @@ from jax.sharding import PartitionSpec
 from repro.kernels.autotune import (
     block_candidates, logistic_candidates, rank_candidates,
 )
-from repro.kernels.ista_step.kernel import fista_step_batched_pallas
+from repro.kernels.ista_step.kernel import (
+    fista_step_batched_pallas, step_vmem_bytes, step_vmem_limit,
+)
 from repro.kernels.ista_step.ops import fista_step_batched, resolve_blocks
 from repro.kernels.logistic_grad.kernel import logistic_grad_pallas
 from repro.kernels.logistic_grad.ops import logistic_grad
@@ -106,6 +109,21 @@ def test_fista_autotune_candidate_compiles(one_chip, r, cand):
     _compile(lambda S, z, c, e: fista_step_batched_pallas(
         S, z, z, c, e, 0.1, 0.5, bp=bp, br=br, bk=bk),
         [(M, P, P), (M, P, r), (M, P, r), (M,)], one_chip)
+
+
+# each deployment's per-chip M solve: the widest r = p tiling the
+# autotuner may pick, with the VMEM limit its call asks for
+MSOLVE_SHAPES = [(384, 1024), (16, 4096), (256, 1024)]
+
+
+@pytest.mark.parametrize("m,p", MSOLVE_SHAPES,
+                         ids=[f"m{m}-p{p}" for m, p in MSOLVE_SHAPES])
+def test_widest_msolve_tile_compiles_with_its_vmem_limit(one_chip, m, p):
+    cand = max(block_candidates(p, p), key=lambda c: step_vmem_bytes(*c))
+    assert step_vmem_limit(*cand) is not None      # past the default
+    _compile(lambda S, z, x, w, c, e: fista_step_batched(
+        S, z, x, w, c, e, 0.1, 0.5, block=cand, interpret=False),
+        [(m, p, p)] * 5 + [(m,)], one_chip)
 
 
 @pytest.mark.parametrize("cand", logistic_candidates(N, P),

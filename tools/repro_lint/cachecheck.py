@@ -4,7 +4,8 @@ Pure stdlib (json + re) — never imports jax, so this runs in CI jobs
 and pre-commit hooks that have no accelerator stack at all. It catches
 the legacy bare-key regression class from PR 4/5: every key in
 `.cache/autotune.json` must be namespaced `"<kernel>/<backend>_<dims>_
-<dtype>"` with the dimension spec and value arity that kernel's sweep
+<dtype>"`, optionally followed by "@<digest>" of the swept candidate
+list, with the dimension spec and value arity that kernel's sweep
 actually writes (`kernels/autotune.py::cache_key`).
 """
 from __future__ import annotations
@@ -18,7 +19,8 @@ from tools.repro_lint.findings import Finding
 
 KEY_RE = re.compile(
     r"^(?P<kernel>[a-z0-9_]+)/(?P<backend>[a-z0-9]+)_"
-    r"(?P<dims>[a-z]+\d+(?:_[a-z]+\d+)*)_(?P<dtype>[a-z0-9]+)$")
+    r"(?P<dims>[a-z]+\d+(?:_[a-z]+\d+)*)_(?P<dtype>[a-z0-9]+)"
+    r"(?:@[0-9a-f]{8})?$")
 
 # kernel namespace -> (ordered dim letters, value arity)
 KERNEL_SHAPES = {

@@ -294,13 +294,16 @@ def check_rank_contract() -> List[Finding]:
 
 def check_solver_contract() -> List[Finding]:
     from repro.kernels.autotune import block_candidates
+    from repro.kernels.ista_step.kernel import (
+        DEFAULT_SCOPED_VMEM, step_vmem_bytes, step_vmem_limit,
+    )
     from repro.kernels.ista_step.ops import (
-        resolve_blocks, step_routes_to_oracle,
+        STEP_VMEM_BUDGET, resolve_blocks, step_routes_to_oracle,
     )
     rel = "src/repro/kernels/ista_step/ops.py"
     findings: List[Finding] = []
     for p in SOLVER_P:
-        for r in SOLVER_R:
+        for r in dict.fromkeys(SOLVER_R + (p,)):      # and r = p
             for block in SOLVER_BLOCKS + tuple(block_candidates(p, r)):
                 if step_routes_to_oracle(p, r, block):
                     continue
@@ -313,6 +316,16 @@ def check_solver_contract() -> List[Finding]:
                         f"dispatchable (p={p}, r={r}, block={block}) "
                         f"resolves non-divisor tiles "
                         f"(bp={bp}, br={br}, bk={bk})"))
+                need = step_vmem_bytes(bp, br, bk)
+                limit = step_vmem_limit(bp, br, bk) or DEFAULT_SCOPED_VMEM
+                if need > STEP_VMEM_BUDGET or need > limit:
+                    findings.append(Finding(
+                        rel, 0, "RL210",
+                        f"dispatchable (p={p}, r={r}, block={block}) -> "
+                        f"(bp={bp}, br={br}, bk={bk}) takes {need} bytes "
+                        f"of VMEM: over STEP_VMEM_BUDGET "
+                        f"({STEP_VMEM_BUDGET}) or the {limit} bytes the "
+                        f"call asks for"))
     return findings
 
 
